@@ -255,7 +255,9 @@ def batch_rollout(fast=True):
 
     The gated column is the batched engine's aggregate us/event (walls
     are min-of-reps); derived records both baselines' events/sec and both
-    speedups against the >=4x target."""
+    speedups against the >=4x target.  The pool runs only on a CPU backend
+    (one process owns an accelerator), so on any other backend both pool
+    baselines and the speedups read "not measured"."""
     from repro.launch.sweep import run_sweep, shutdown_pool
 
     B = 16
@@ -265,6 +267,20 @@ def batch_rollout(fast=True):
     prof = run_sweep(serial=True, profile=True, **kw)
     events = sum(r["profile"]["events"] for r in prof["results"])
     reps = 3 if fast else 10
+    batched_wall = float("inf")
+    rep = None
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        rep = run_sweep(serial=True, engine="batched", **kw)
+        batched_wall = min(batched_wall, time.perf_counter() - t0)
+    assert rep["config"]["batched_cells"] == B, "batched engine skipped cells"
+    derived = (f"B={B};events={events};"
+               f"batched_wall_s={batched_wall:.3f};"
+               f"batched_events_per_s={events / max(batched_wall, 1e-9):.0f};")
+    if jax.default_backend() != "cpu":
+        derived += (f"pool_wall_s=not measured ({jax.default_backend()} "
+                    f"backend);speedup=not measured;target=4.00x")
+        return [row("batch_rollout", batched_wall / max(events, 1), derived)]
     shutdown_pool()                            # cold-driver baseline
     t0 = time.perf_counter()
     run_sweep(workers=1, **kw)
@@ -275,21 +291,12 @@ def batch_rollout(fast=True):
         run_sweep(workers=1, **kw)
         pool_warm = min(pool_warm, time.perf_counter() - t0)
     shutdown_pool()
-    batched_wall = float("inf")
-    rep = None
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        rep = run_sweep(serial=True, engine="batched", **kw)
-        batched_wall = min(batched_wall, time.perf_counter() - t0)
-    assert rep["config"]["batched_cells"] == B, "batched path fell back"
     return [row(
         "batch_rollout", batched_wall / max(events, 1),
-        f"B={B};events={events};pool_wall_s={pool_wall:.3f};"
-        f"pool_warm_wall_s={pool_warm:.3f};"
-        f"batched_wall_s={batched_wall:.3f};"
+        derived
+        + f"pool_wall_s={pool_wall:.3f};pool_warm_wall_s={pool_warm:.3f};"
         f"pool_events_per_s={events / max(pool_wall, 1e-9):.0f};"
         f"pool_warm_events_per_s={events / max(pool_warm, 1e-9):.0f};"
-        f"batched_events_per_s={events / max(batched_wall, 1e-9):.0f};"
         f"speedup={pool_wall / batched_wall:.2f}x;"
         f"speedup_warm={pool_warm / batched_wall:.2f}x;target=4.00x")]
 

@@ -15,10 +15,11 @@ avoid zero padding for the reason the paper cites — large zero regions hurt
 training), and the output is cropped back.
 Inference goes through one module-level jitted apply shared by every
 :class:`UNet` instance (keyed on parameter shapes + input shape, so all
-estimators in a process — and all sweep workers forked from it — reuse one
-compiled executable per shape instead of recompiling per instance), and
-batches are padded to power-of-two buckets so a handful of compilations
-serve any batch size.
+estimators in a process reuse one compiled executable per shape instead of
+recompiling per instance), and batches are padded to power-of-two buckets
+so a handful of compilations serve any batch size.  Every convolution runs
+at ``PRECISION``: float32 on every backend, where the TPU's default would
+use bfloat16 passes.
 """
 from __future__ import annotations
 
@@ -31,6 +32,12 @@ from jax import lax
 from repro.utils.tree import ParamBuilder, fan_in_init
 
 DN = ("NHWC", "HWIO", "NHWC")
+
+# The artifacts were trained and tested in float32, and the outputs feed
+# Algorithm 1's discrete partition choice, where a last-digit change can
+# flip a decision.  A no-op on the CPU; on the TPU it replaces the default
+# bfloat16 passes with float32-accurate ones.
+PRECISION = lax.Precision.HIGHEST
 
 
 def _conv_init(k_h, k_w, c_in):
@@ -62,14 +69,14 @@ def init(key, levels: int = 3, jobs: int = 7, dtype=jnp.float32):
 def _conv(x, p, name, stride=1):
     y = lax.conv_general_dilated(
         x, p[f"{name}_w"], window_strides=(stride, stride), padding="SAME",
-        dimension_numbers=DN)
+        dimension_numbers=DN, precision=PRECISION)
     return y + p[f"{name}_b"]
 
 
 def _conv_t(x, p, name):
     y = lax.conv_transpose(
         x, p[f"{name}_w"], strides=(2, 2), padding="SAME",
-        dimension_numbers=DN)
+        dimension_numbers=DN, precision=PRECISION)
     return y + p[f"{name}_b"]
 
 
@@ -119,11 +126,10 @@ def warm_jit_cache(levels: int = 3, jobs: int = 7,
                    batch_buckets=(1, 2, 4, 8)) -> None:
     """Compile the shared apply for the standard shapes ahead of time.
 
-    Call this in a process that will fork workers (e.g. the sweep engine):
-    the forked children inherit the parent's XLA compilation cache, so each
-    worker skips its own multi-hundred-ms compile.  Compilation is keyed on
-    parameter *shapes*, so warming with freshly-initialized params also
-    covers artifact-loaded ones.
+    The sweep engine calls this once per process before simulating (and
+    each spawned pool worker once more: spawn shares no compiled code with
+    the parent).  Compilation is keyed on parameter *shapes*, so warming
+    with freshly-initialized params also covers artifact-loaded ones.
     """
     # misolint: disable=MS102 -- shape-only jit warm-up: params are discarded
     # and XLA keys its compile cache on shapes, so any constant key works

@@ -3,11 +3,12 @@
 Central controller + per-accelerator server API over a job trace:
 FCFS queue -> least-loaded placement -> MPS profiling (interference-prone
 co-run) -> U-Net MPS->MIG translation -> Algorithm 1 -> dynamic partitions.
-The execution backend is the event simulator (no A100s/TPUs in this
-container, DESIGN.md §2); with ``--space tpu`` the accelerators are v5e pods
+The execution backend is the event simulator (DESIGN.md §2): the
+accelerators are simulated, and only the U-Net forward runs on the device
+JAX holds.  With ``--space tpu`` the accelerators are v5e pods
 partitioned into contiguous sub-mesh slices and each slice maps onto a
-``launch.mesh.make_slice_mesh`` JAX mesh (printed per scheduling decision
-with ``--show-meshes``).
+JAX mesh of its ``mesh_shape`` with axes ``launch.mesh.SLICE_AXES``
+(printed per slice with ``--show-meshes``, without building it on devices).
 
 ``--policy`` accepts any registered scheduling policy
 (``repro/core/sim/policies/``):
@@ -47,13 +48,8 @@ parallel by ``python -m repro.launch.sweep``.
 from __future__ import annotations
 
 import argparse
-import os
+import math
 import sys
-
-if "--show-meshes" in sys.argv:
-    # slice meshes need placeholder devices; must be set before first jax init
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=256").strip()
 
 from repro.core.estimators import NoisyEstimator, OracleEstimator, UNetEstimator
 from repro.core.partitions import a100_mig_space, tpu_pod_space
@@ -149,6 +145,8 @@ def _print_robustness(metrics) -> None:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     if args.fleet:
         from repro.core.fleet import describe_fleet, parse_fleet
@@ -215,14 +213,13 @@ def main(argv=None):
     metrics = simulate(jobs, cfg, space, pm, est)
 
     if args.show_meshes and args.space == "tpu":
-        from repro.launch.mesh import make_slice_mesh
+        from repro.launch.mesh import SLICE_AXES
         print("[cluster] slice -> JAX mesh mapping:")
         for size in sorted(space.slices):
             st = space.slices[size]
             if st.mesh_shape:
-                mesh = make_slice_mesh(*st.mesh_shape)
                 print(f"  {st.name}: mesh {st.mesh_shape} axes "
-                      f"{mesh.axis_names} = {mesh.devices.size} devices")
+                      f"{SLICE_AXES} = {math.prod(st.mesh_shape)} devices")
 
     b = metrics.breakdown
     print(f"[cluster] {args.policy} on {args.accelerators} x {args.space}: "

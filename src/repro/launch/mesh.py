@@ -23,10 +23,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
-def make_slice_mesh(rows: int, cols: int = 16):
-    """A MISO pod sub-slice (contiguous row range) as its own mesh —
-    what a job scheduled on a TPUPodSpace slice actually runs under."""
-    return jax.make_mesh((rows, cols), ("data", "model"), axis_types=_auto(2))
+#: axis names of the mesh a job on a MISO pod sub-slice (a contiguous row
+#: range, ``SliceType.mesh_shape``) runs under
+SLICE_AXES = ("data", "model")
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 1):

@@ -35,8 +35,12 @@ Two execution engines share the cell-build path (``--engine``):
   in-process lockstep replica batch (``core/sim/batch.py``): estimator
   forwards and Algorithm-1 solves fuse across cells, metrics stay
   bit-identical per cell, and ``config.batched_cells`` records how many
-  cells actually ran batched.  Profiled sweeps and groups that fail to
-  build or run fall back to the pool path per cell.
+  cells actually ran batched.  Profiled sweeps run on the pool or serial
+  path instead; a group that fails to build or run raises.
+
+One process owns an accelerator: the pool starts only on a CPU backend, and
+on any other backend a sweep runs ``--engine batched`` or ``--serial`` in
+the process that holds the device.
 
 Warm-pool execution (the driver loop that makes cheap rollouts cheap):
 
@@ -63,7 +67,8 @@ Warm-pool execution (the driver loop that makes cheap rollouts cheap):
 Hardening (chaos sweeps run long and can die mid-grid): every cell runs
 under a per-cell wall-clock budget (``--cell-timeout``, SIGALRM) with
 bounded retry (``--retries``); a cell that still fails is recorded in
-``report["errors"]`` instead of sinking the whole sweep, and ``--resume
+``report["errors"]`` instead of sinking the whole sweep (the CLI then exits
+non-zero once the report is written), and ``--resume
 partial.json`` skips cells already present in an earlier report of the
 same schema version (error cells are always re-run).  POSIX reserves
 signal delivery for the main thread: when the runner is embedded off the
@@ -142,6 +147,14 @@ def _get_pool(workers: Optional[int]) -> ProcessPoolExecutor:
     pool exists (or sizes a new one to the CPU count); an explicit size
     that differs from the live pool recycles it."""
     global _POOL, _POOL_WORKERS
+    import jax
+    if jax.default_backend() != "cpu":
+        # every worker would load the accelerator runtime, which only one
+        # process may hold; the one that holds it is this one
+        raise RuntimeError(
+            f"the sweep worker pool runs only on a CPU backend (this process "
+            f"holds {jax.default_backend()!r}); use --engine batched or "
+            f"--serial")
     want = workers or _POOL_WORKERS or (os.cpu_count() or 1)
     if _POOL is not None and want != _POOL_WORKERS:
         shutdown_pool()
@@ -340,7 +353,7 @@ def run_task(task: Dict) -> Dict:
     return out
 
 
-def _run_batched(tasks: List[Dict]) -> Tuple[List[Dict], List[Dict]]:
+def _run_batched(tasks: List[Dict]) -> List[Dict]:
     """Run sweep cells through the in-process replica-batched engine.
 
     Cells coalesce by resolved fleet spec: one spec string means one fleet
@@ -351,11 +364,9 @@ def _run_batched(tasks: List[Dict]) -> Tuple[List[Dict], List[Dict]]:
     scalar engine, and ``wall_s`` is the group's wall-clock amortized over
     its members (lockstep execution has no per-cell attribution).
 
-    Returns ``(results, fallback_tasks)``: a group whose build or run
-    raises falls back wholesale to the warm-pool path (which retries,
-    times out and error-records per cell), as do any cells this function
-    never attempts.  Per-cell SIGALRM budgets cannot interrupt a lockstep
-    round, so ``cell_timeout`` is enforced only on fallback cells.
+    A group whose build or run raises propagates the error.  Per-cell
+    SIGALRM budgets cannot interrupt a lockstep round, so ``retries`` and
+    ``cell_timeout`` do not apply to batched cells.
     """
     from repro.core.scenarios import get_scenario
     from repro.core.sim.batch import BatchSim
@@ -366,21 +377,14 @@ def _run_batched(tasks: List[Dict]) -> Tuple[List[Dict], List[Dict]]:
         sc = get_scenario(task["scenario"])
         groups.setdefault(task.get("fleet") or sc.fleet, []).append(task)
     results: List[Dict] = []
-    fallback: List[Dict] = []
     for members in groups.values():
         t0 = time.time()
-        try:
-            built = [_build_cell(t) for t in members]
-            ms = BatchSim([sim for sim, _ in built]).run()
-        except Exception:
-            # anything from a bad scenario to a diverging replica: the
-            # scalar pool path owns per-cell isolation and error records
-            fallback.extend(members)
-            continue
+        built = [_build_cell(t) for t in members]
+        ms = BatchSim([sim for sim, _ in built]).run()
         wall = (time.time() - t0) / len(members)
         results.extend(_cell_result(meta, m, wall)
                        for (_, meta), m in zip(built, ms))
-    return results, fallback
+    return results
 
 
 class CellTimeout(Exception):
@@ -485,16 +489,14 @@ def run_sweep(policies: Sequence[str], scenarios: Sequence[str],
     error cells are re-run).  ``trace_cache`` names a directory for the
     on-disk tier of the content-addressed trace cache (None = in-process
     memo only).  Parallel grids run on the persistent warm pool — see the
-    module docstring.
+    module docstring; it refuses to start on a non-CPU backend.
 
     ``engine="batched"`` routes cells through the in-process
-    replica-batched engine first: cells sharing a resolved fleet spec run
-    in lockstep with fused estimator / Algorithm-1 services and
-    bit-identical per-cell metrics (coalesce and fallback rules:
-    :func:`_run_batched`).  Profiled sweeps keep the pool path — the
-    per-component clocks are not accumulated through the collect
-    pipeline — and any cell the batched engine could not run falls back
-    to the pool/serial path below."""
+    replica-batched engine: cells sharing a resolved fleet spec run in
+    lockstep with fused estimator / Algorithm-1 services and bit-identical
+    per-cell metrics (coalesce rules: :func:`_run_batched`).  Profiled
+    sweeps keep the pool/serial path — the per-component clocks are not
+    accumulated through the collect pipeline."""
     tasks = [{"policy": p, "placer": pl, "objective": ob, "scenario": sc,
               "seed": s, "fleet": fleet, "n_jobs": n_jobs, "mtbf": mtbf,
               "profile": profile, "retries": retries,
@@ -517,7 +519,7 @@ def run_sweep(policies: Sequence[str], scenarios: Sequence[str],
     t0 = time.time()
     batched_results: List[Dict] = []
     if engine == "batched" and tasks and not profile:
-        batched_results, tasks = _run_batched(tasks)
+        batched_results, tasks = _run_batched(tasks), []
     if workers is None and not serial:
         # tiny grids (e.g. the CI smoke sweep) finish faster in-process than
         # a pool takes to start; an explicit --workers always gets the pool
@@ -594,8 +596,7 @@ def run_sweep(policies: Sequence[str], scenarios: Sequence[str],
             "resumed_cells": len(resumed),
             "trace_cache": trace_cache,
             "engine": engine,
-            # cells the batched engine actually ran (0 under --profile or
-            # when every group fell back to the pool path)
+            # cells the batched engine ran (0 under --profile)
             "batched_cells": len(batched_results),
         },
         "wall_s_total": time.time() - t0,
@@ -732,8 +733,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "coalesces cells that share a fleet spec into "
                          "one in-process lockstep replica batch with "
                          "fused estimator/Algorithm-1 services "
-                         "(bit-identical metrics; profiled sweeps and "
-                         "failed groups fall back to the pool)")
+                         "(bit-identical metrics; profiled sweeps keep the "
+                         "pool). On a non-CPU backend the pool refuses to "
+                         "start: use 'batched' or --serial")
     ap.add_argument("--out", default="BENCH_sweep.json",
                     help="JSON report path")
     return ap
@@ -761,6 +763,8 @@ def main(argv=None) -> int:
     for ob in objectives or ():
         get_objective(ob)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     report = run_sweep(policies, scenarios, seeds=list(range(args.seeds)),
                        placers=placers, objectives=objectives,
                        fleet=args.fleet, n_jobs=args.jobs,
@@ -774,7 +778,7 @@ def main(argv=None) -> int:
         f.write("\n")
     _print_summary(report)
     print(f"[sweep] report -> {args.out}")
-    return 0
+    return 1 if report["errors"] else 0
 
 
 if __name__ == "__main__":

@@ -1,6 +1,9 @@
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# entry points under test turn the persistent compile cache on; tests run
+# without it (the described-TPU compiles could not read their entries back)
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 import jax
 import pytest

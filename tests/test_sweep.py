@@ -150,6 +150,43 @@ def test_error_cell_isolated_not_fatal(monkeypatch):
     json.dumps(rep)
 
 
+def test_cli_exits_nonzero_on_error_cell(tmp_path, monkeypatch):
+    """The CLI writes the report with its error records and then exits
+    non-zero, so a crashed cell cannot pass as a finished sweep."""
+    from repro.launch import sweep
+
+    real = sweep.run_task
+
+    def flaky(task):
+        if task["seed"] == 1:
+            raise RuntimeError("boom")
+        return real(task)
+
+    monkeypatch.setattr(sweep, "run_task", flaky)
+    out = tmp_path / "report.json"
+    rc = sweep.main(["--scenarios", "smoke", "--seeds", "2", "--policies",
+                     "miso", "--serial", "--out", str(out)])
+    assert rc != 0
+    rep = json.loads(out.read_text())
+    (err,) = rep["errors"]
+    assert "RuntimeError: boom" in err["error"]
+    assert [r["seed"] for r in rep["results"]] == [0]
+
+
+def test_pool_refuses_non_cpu_backend(monkeypatch):
+    """On an accelerator backend the pool never starts: its workers would
+    each try to load the device runtime that this process holds."""
+    import jax
+
+    from repro.launch import sweep
+
+    live = sweep._POOL       # an earlier test's CPU pool may be alive
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="--engine batched or --serial"):
+        sweep.run_sweep(["miso"], ["smoke"], seeds=[0, 1], workers=3)
+    assert sweep._POOL is live
+
+
 def test_cell_timeout_records_error(monkeypatch):
     """A cell that exceeds its wall-clock budget is killed by the SIGALRM
     guard and recorded, not hung forever."""
@@ -571,18 +608,17 @@ def test_batched_engine_coalesces_by_fleet():
 
 
 def test_batched_engine_group_failure_falls_back(monkeypatch):
-    """A group whose lockstep run dies falls back to the per-cell scalar
-    path: the sweep still returns every cell, with batched_cells == 0."""
+    """A group whose lockstep run dies raises out of the sweep: no
+    fallback path re-runs it elsewhere and hides the failure."""
     from repro.core.sim import batch as batch_mod
 
     def boom(self):
         raise RuntimeError("injected lockstep failure")
 
     monkeypatch.setattr(batch_mod.BatchSim, "run", boom)
-    rep = run_sweep(["miso"], ["smoke"], seeds=[0, 1], serial=True,
-                    engine="batched")
-    assert rep["config"]["batched_cells"] == 0
-    assert len(rep["results"]) == 2 and not rep["errors"]
+    with pytest.raises(RuntimeError, match="injected lockstep failure"):
+        run_sweep(["miso"], ["smoke"], seeds=[0, 1], serial=True,
+                  engine="batched")
 
 
 def test_batched_engine_profile_falls_back():
