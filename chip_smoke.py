@@ -101,6 +101,7 @@ class Probe:
 
         from repro.core.predictor import unet
         from repro.core.sim.batch import BatchSim
+        from repro.core.sim.prof import new_prof
 
         self.compiles = []            # function name per backend compile
         self.spans = []               # (start, end) of compile work
@@ -129,9 +130,7 @@ class Probe:
         def counted_run(batch):
             for sim in batch.sims:
                 if sim.prof is None:
-                    sim.prof = dict.fromkeys(("placement_s", "alg1_s",
-                                              "estimator_s", "total_s",
-                                              "events"), 0.0)
+                    sim.prof = new_prof()
             out = run(batch)
             probe.events += int(sum(s.prof["events"] for s in batch.sims))
             return out

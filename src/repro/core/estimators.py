@@ -84,15 +84,16 @@ class OracleEstimator:
         return est
 
     def estimate(self, profs: Sequence[JobProfile], mps_matrix=None,
-                 qos=None) -> List[Dict[int, float]]:
+                 qos=None, prof=None) -> List[Dict[int, float]]:
         qos = qos or [0] * len(profs)
         return [self._estimate_one(p, q) for p, q in zip(profs, qos)]
 
-    def estimate_batch(self, requests: Sequence[EstimateRequest]
+    def estimate_batch(self, requests: Sequence[EstimateRequest], prof=None
                        ) -> List[List[Dict[int, float]]]:
         """Default batched path: per-request ``estimate`` in request order
         (exact for any estimator whose estimate is per-request; overridden
-        where a fused pass exists)."""
+        where a fused pass exists).  ``prof`` (profile buckets) is
+        accepted and ignored: only the U-Net has buckets of its own."""
         return [self.estimate(profs, mat, qos)
                 for profs, mat, qos in requests]
 
@@ -110,7 +111,7 @@ class NoisyEstimator(OracleEstimator):
         self.sigma = sigma
         self.rng = np.random.default_rng(seed)
 
-    def estimate(self, profs, mps_matrix=None, qos=None):
+    def estimate(self, profs, mps_matrix=None, qos=None, prof=None):
         qos = qos or [0] * len(profs)
         out = []
         for p, q in zip(profs, qos):
@@ -169,26 +170,51 @@ class UNetEstimator:
         return m / np.maximum(m.max(axis=0, keepdims=True), 1e-9)
 
     def estimate(self, profs, mps_matrix: Optional[np.ndarray] = None,
-                 qos=None) -> List[Dict[int, float]]:
+                 qos=None, prof=None) -> List[Dict[int, float]]:
+        """``prof``: profile buckets (``core/sim/prof.py``) that the
+        forward's host, wait and post-processing time go to."""
         if mps_matrix is None:
             mps_matrix = self.measure_mps(profs)
-        pred = np.asarray(self.net(mps_matrix))            # (3, J)
-        return self._postprocess(profs, pred, qos)
+        if prof is None:
+            pred = np.asarray(self.net(mps_matrix))            # (3, J)
+            return self._postprocess(profs, pred, qos)
+        return self._profiled(
+            prof, lambda: self.net(mps_matrix),
+            lambda pred: self._postprocess(profs, pred, qos))
 
-    def estimate_batch(self, requests: Sequence[EstimateRequest]
+    def estimate_batch(self, requests: Sequence[EstimateRequest], prof=None
                        ) -> List[List[Dict[int, float]]]:
         """Fused path: all B requests' matrices go through one stacked
         ``(B, levels, jobs)`` jitted forward (see module docstring for the
         numerical contract); measurement (and thus any RNG use) happens in
-        request order before the forward."""
+        request order before the forward.  ``prof`` as in :meth:`estimate`."""
         if not requests:
             return []
         mats = [np.asarray(mat if mat is not None else self.measure_mps(profs),
                            dtype=np.float32)
                 for profs, mat, _ in requests]
-        preds = np.asarray(self.net(np.stack(mats)))       # (B, 3, J)
-        return [self._postprocess(profs, pred, qos)
-                for (profs, _, qos), pred in zip(requests, preds)]
+
+        def post(preds):
+            return [self._postprocess(profs, pred, qos)
+                    for (profs, _, qos), pred in zip(requests, preds)]
+        if prof is None:
+            return post(np.asarray(self.net(np.stack(mats))))  # (B, 3, J)
+        return self._profiled(prof, lambda: self.net(np.stack(mats)), post)
+
+    @staticmethod
+    def _profiled(prof, launch, post):
+        """One forward, timed: ``launch()`` returns as soon as the forward
+        is enqueued (JAX dispatches asynchronously), the readback waits for
+        it, then ``post`` turns the output into estimates."""
+        from repro.core.sim.prof import timed   # core.sim imports this module
+        with timed(prof, "unet_host_s", "unet.host"):
+            out = launch()
+        with timed(prof, "unet_wait_s", "unet.wait"):
+            pred = np.asarray(out)
+        with timed(prof, "estimator_post_s", "estimator.post"):
+            ests = post(pred)
+        prof["unet_calls"] += 1.0
+        return ests
 
     def _postprocess(self, profs, pred: np.ndarray,
                      qos=None) -> List[Dict[int, float]]:

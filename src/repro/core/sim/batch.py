@@ -60,6 +60,7 @@ import numpy as np
 from repro.core.metrics import TraceMetrics
 from repro.core.optimizer import optimize_partition, optimize_partition_batch
 from repro.core.sim.policies.base import EstimateWork, Policy, RepartDecision
+from repro.core.sim.prof import new_prof, split, timed
 from repro.core.sim.soa import settle_rows
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard
@@ -181,7 +182,12 @@ class BatchSim:
         for i, sim in enumerate(self.sims):
             if self.done[i]:
                 continue
-            r = sim.run_until_collect()
+            prof = sim.prof
+            if prof is None:
+                r = sim.run_until_collect()
+            else:
+                with timed(prof, "collect_s", "sim.collect"):
+                    r = sim.run_until_collect()
             if r is None:
                 self.done[i] = True
             else:
@@ -194,12 +200,22 @@ class BatchSim:
             # stage B: resume each tick, collect its pending decisions
             decisions: List[RepartDecision] = []
             for p in pendings:
-                decisions.extend(p.apply())
+                prof = p.sim.prof
+                if prof is None:
+                    decisions.extend(p.apply())
+                else:
+                    with timed(prof, "round_apply_s", "batch.apply"):
+                        decisions.extend(p.apply())
             # stage C: fused Algorithm-1 solves across replicas
             self._solve_decisions(decisions)
             # stage D: apply + finalize, completing each replica's tick
             for p in pendings:
-                p.finish()
+                prof = p.sim.prof
+                if prof is None:
+                    p.finish()
+                else:
+                    with timed(prof, "round_apply_s", "batch.apply"):
+                        p.finish()
         self.rounds += 1
         return not all(self.done)
 
@@ -228,17 +244,20 @@ class BatchSim:
         """Stage A: fill ``w.ests`` for every collected MPS window via one
         ``estimate_batch`` call per estimator object.  Measurements (and
         their noise draws) already happened at collect time inside each
-        replica; the forward is pure, so cross-replica fusion is exact."""
+        replica; the forward is pure, so cross-replica fusion is exact.
+
+        Profiled replicas get the stage's time and the estimator's buckets
+        in proportion to the windows each sent."""
         if not works:
             return
-        by_est: Dict[int, List[EstimateWork]] = {}
-        for w in works:
-            by_est.setdefault(id(w.g.estimator), []).append(w)
-        for group in by_est.values():
-            requests = [(w.profs, w.mat, w.qos) for w in group]
-            ests = group[0].g.estimator.estimate_batch(requests)
-            for w, est in zip(group, ests):
-                w.ests = est
+        owners = [w.prof for w in works]
+        if not any(owners):
+            _estimate_groups(works, None)
+            return
+        scratch = new_prof()
+        with timed(scratch, "fused_estimator_s", "batch.stage_a"):
+            _estimate_groups(works, scratch)
+        split(scratch, owners)
 
     @staticmethod
     def _solve_decisions(decisions: List[RepartDecision]) -> None:
@@ -251,28 +270,20 @@ class BatchSim:
         feasible-first pass returns None: exactly
         ``Policy.choose_partition_batch``, element for element.  A policy
         class that overrides ``choose_partition`` keeps its own per-decision
-        logic (same guard the scalar batch path applies)."""
+        logic (same guard the scalar batch path applies).
+
+        Profiled replicas get the stage's time in proportion to the
+        decisions each sent."""
         if not decisions:
             return
-        groups: Dict[tuple, List[RepartDecision]] = {}
-        for d in decisions:
-            pol = d.policy
-            if type(pol).choose_partition is not Policy.choose_partition:
-                d.choice = pol.choose_partition(d.speeds, space=d.g.space,
-                                                power=d.g.power)
-                continue
-            key = (id(d.g.space), id(d.g.power), pol.objective.memo_key())
-            groups.setdefault(key, []).append(d)
-        for group in groups.values():
-            d0 = group[0]
-            space, power = d0.g.space, d0.g.power
-            objective = d0.policy.objective
-            first = optimize_partition_batch(
-                space, [d.speeds for d in group], require_feasible=True,
-                objective=objective, power=power)
-            for d, c in zip(group, first):
-                d.choice = c if c is not None else optimize_partition(
-                    space, d.speeds, objective=objective, power=power)
+        owners = [d.policy.sim.prof for d in decisions]
+        if not any(owners):
+            _solve_groups(decisions)
+            return
+        scratch = new_prof()
+        with timed(scratch, "fused_alg1_s", "batch.stage_c"):
+            _solve_groups(decisions)
+        split(scratch, owners)
 
     # --------------------------------------------------------- observation
 
@@ -291,3 +302,36 @@ class BatchSim:
         out.update(self.fleet_state.scalars())
         out.update(self.fleet_state.resident_matrix())
         return out
+
+
+def _estimate_groups(works: List[EstimateWork], prof) -> None:
+    by_est: Dict[int, List[EstimateWork]] = {}
+    for w in works:
+        by_est.setdefault(id(w.g.estimator), []).append(w)
+    for group in by_est.values():
+        requests = [(w.profs, w.mat, w.qos) for w in group]
+        ests = group[0].g.estimator.estimate_batch(requests, prof=prof)
+        for w, est in zip(group, ests):
+            w.ests = est
+
+
+def _solve_groups(decisions: List[RepartDecision]) -> None:
+    groups: Dict[tuple, List[RepartDecision]] = {}
+    for d in decisions:
+        pol = d.policy
+        if type(pol).choose_partition is not Policy.choose_partition:
+            d.choice = pol.choose_partition(d.speeds, space=d.g.space,
+                                            power=d.g.power)
+            continue
+        key = (id(d.g.space), id(d.g.power), pol.objective.memo_key())
+        groups.setdefault(key, []).append(d)
+    for group in groups.values():
+        d0 = group[0]
+        space, power = d0.g.space, d0.g.power
+        objective = d0.policy.objective
+        first = optimize_partition_batch(
+            space, [d.speeds for d in group], require_feasible=True,
+            objective=objective, power=power)
+        for d, c in zip(group, first):
+            d.choice = c if c is not None else optimize_partition(
+                space, d.speeds, objective=objective, power=power)

@@ -56,6 +56,7 @@ from repro.core.sim.faults import FaultInjector, get_fault_injector
 from repro.core.sim.gpu import (CKPT, DEGRADED, GPU, HEALTHY, IDLE, MIG_RUN,
                                 MPS_PROF, QUARANTINED)
 from repro.core.sim.index import FleetIndex, WorkAggregate
+from repro.core.sim.prof import new_prof
 from repro.core.sim.soa import FleetState
 from repro.core.sim.policies import get_policy
 
@@ -107,7 +108,8 @@ class SimConfig:
     # on each MPS-matrix entry; drawn from the simulator RNG per window
     mps_noise_sigma: float = 0.0
     # collect per-component wall-clock (placement / Algorithm-1 / estimator /
-    # event loop) into ClusterSim.prof; surfaced by `launch/sweep --profile`
+    # event loop / BatchSim stages) into ClusterSim.prof (core/sim/prof.py);
+    # surfaced by `launch/sweep --profile`
     profile: bool = False
 
 
@@ -154,11 +156,11 @@ class ClusterSim:
         self.profile_cache: Dict[tuple, Dict[int, float]] = {}  # (mi_group, space)
         self.completed: List[int] = []
         self._counter = itertools.count()
-        # per-component wall-clock buckets (None = profiling off, the hot
-        # paths check `prof is not None` and pay nothing)
+        # per-component wall-clock buckets (core/sim/prof.py; None =
+        # profiling off, the hot paths check `prof is not None` and pay
+        # nothing)
         self.prof: Optional[Dict[str, float]] = (
-            {"placement_s": 0.0, "alg1_s": 0.0, "estimator_s": 0.0,
-             "total_s": 0.0, "events": 0.0} if cfg.profile else None)
+            new_prof() if cfg.profile else None)
         # -- placement hot-path structures (see repro.core.sim.index):
         # in-system remaining-work aggregate (hetero-speed split point) ...
         self.work_agg = WorkAggregate()

@@ -82,15 +82,16 @@ class EstimateWork:
     ``estimate_batch`` call per group — one stacked predictor forward for
     every same-tick window across every replica."""
 
-    __slots__ = ("g", "jids", "profs", "qos", "mat", "ests")
+    __slots__ = ("g", "jids", "profs", "qos", "mat", "ests", "prof")
 
-    def __init__(self, g: GPU, jids, profs, qos, mat):
+    def __init__(self, g: GPU, jids, profs, qos, mat, prof=None):
         self.g = g
         self.jids = jids
         self.profs = profs
         self.qos = qos
         self.mat = mat          # measured MPS matrix (None: estimator-only)
         self.ests: Optional[list] = None   # filled by the owner (stage A)
+        self.prof = prof        # the replica's profile buckets (None: off)
 
 
 class RepartDecision:

@@ -17,6 +17,7 @@ from repro.core.jobs import Job
 from repro.core.sim.gpu import CKPT, GPU, IDLE, MIG_RUN, MPS_PROF
 from repro.core.sim.policies.base import (EstimateWork, Policy,
                                           register_policy)
+from repro.core.sim.prof import timed
 
 
 @register_policy
@@ -73,8 +74,8 @@ class MisoPolicy(Policy):
         for group in by_est.values():
             requests = [(mixes[g.gid][1], mats[g.gid], mixes[g.gid][2])
                         for g in group]
-            for g, est in zip(group,
-                              group[0].estimator.estimate_batch(requests)):
+            for g, est in zip(group, group[0].estimator.estimate_batch(
+                    requests, prof=prof)):
                 ests[g.gid] = est
         if prof is not None:
             prof["estimator_s"] += time.perf_counter() - t0
@@ -100,7 +101,7 @@ class MisoPolicy(Policy):
         for g in prof_gs:
             jids, profs, qos = self._mix(g)
             work.append(EstimateWork(g, jids, profs, qos,
-                                     self._measure(g, profs)))
+                                     self._measure(g, profs), self.sim.prof))
         return work
 
     def apply_phase_end(self, gs, work):
@@ -188,7 +189,7 @@ class MisoPolicy(Policy):
         prof = self.sim.prof
         t0 = time.perf_counter() if prof is not None else 0.0
         mps_mat = self._measure(g, profs)
-        ests = g.estimator.estimate(profs, mps_mat, qos=qos)
+        ests = g.estimator.estimate(profs, mps_mat, qos=qos, prof=prof)
         if prof is not None:
             prof["estimator_s"] += time.perf_counter() - t0
         self._store_estimates(g, jids, ests)
@@ -213,8 +214,13 @@ class MisoPolicy(Policy):
         # thread the simulator's noise stream so every profiling window
         # draws fresh measurement noise (Fig 14 sensitivity) without
         # disturbing the main RNG's failure-injection schedule
-        return g.estimator.measure_mps(
-            profs, noise_sigma=sim.cfg.mps_noise_sigma, rng=sim.noise_rng)
+        prof = sim.prof
+        if prof is None:
+            return g.estimator.measure_mps(
+                profs, noise_sigma=sim.cfg.mps_noise_sigma, rng=sim.noise_rng)
+        with timed(prof, "mps_measure_s", "mps.measure"):
+            return g.estimator.measure_mps(
+                profs, noise_sigma=sim.cfg.mps_noise_sigma, rng=sim.noise_rng)
 
     def _store_estimates(self, g: GPU, jids, ests):
         """Record the estimator's output on the GPU (and the shared

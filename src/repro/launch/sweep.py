@@ -35,8 +35,7 @@ Two execution engines share the cell-build path (``--engine``):
   in-process lockstep replica batch (``core/sim/batch.py``): estimator
   forwards and Algorithm-1 solves fuse across cells, metrics stay
   bit-identical per cell, and ``config.batched_cells`` records how many
-  cells actually ran batched.  Profiled sweeps run on the pool or serial
-  path instead; a group that fails to build or run raises.
+  cells actually ran batched; a group that fails to build or run raises.
 
 One process owns an accelerator: the pool starts only on a CPU backend, and
 on any other backend a sweep runs ``--engine batched`` or ``--serial`` in
@@ -331,26 +330,42 @@ def run_task(task: Dict) -> Dict:
     run_s = time.perf_counter() - t_run0
     out = _cell_result(meta, m, time.time() - t0)
     if profile:
-        p = sim.prof
-        out["profile"] = {
-            "placement_s": p["placement_s"],
-            "alg1_s": p["alg1_s"],
-            "estimator_s": p["estimator_s"],
-            # everything else the run loop did: heap churn, accounting,
-            # phase bookkeeping
-            "event_loop_s": max(0.0, p["total_s"] - p["placement_s"]
-                                - p["alg1_s"] - p["estimator_s"]),
-            "total_s": p["total_s"],
-            "events": int(p["events"]),
-            # per-cell overhead buckets (everything that is not the
-            # simulation itself); trace_src says whether job generation
-            # was skipped by the content-addressed cache
-            "gen_s": meta["gen_s"],
-            "setup_s": meta["setup_s"],
-            "trace_src": meta["trace_src"],
-            "overhead_s": max(0.0, out["wall_s"] - run_s),
-        }
+        out["profile"] = _profile_record(sim.prof, meta)
+        # everything that is not the simulation itself
+        out["profile"]["overhead_s"] = max(0.0, out["wall_s"] - run_s)
     return out
+
+
+def _profile_record(p: Dict[str, float], meta: Dict) -> Dict:
+    """A profiled cell's ``profile`` entry from its ``ClusterSim.prof``
+    (``core/sim/prof.py``).  A scalar cell's time is its ``run()``
+    (``total_s``); a batched cell's is its replica's rounds: its own event
+    loop (``collect_s``), stages B and D (``round_apply_s``) and its shares
+    of the fused stages A and C.  One formula covers both, since each
+    engine leaves the other's buckets at 0.  Every bucket is copied;
+    ``alg1_s`` and ``estimator_s`` become the whole layer, inline work plus
+    the fused stage's share."""
+    total = (p["total_s"] + p["collect_s"] + p["round_apply_s"]
+             + p["fused_estimator_s"] + p["fused_alg1_s"])
+    estimator = p["estimator_s"] + p["fused_estimator_s"]
+    alg1 = p["alg1_s"] + p["fused_alg1_s"]
+    rec = dict(p)
+    rec.update({
+        "alg1_s": alg1,
+        "estimator_s": estimator,
+        # everything else the run loop did: heap churn, accounting,
+        # phase bookkeeping
+        "event_loop_s": max(0.0, total - p["placement_s"] - alg1
+                            - estimator),
+        "total_s": total,
+        "events": int(p["events"]),
+        # per-cell set-up buckets; trace_src says whether job generation
+        # was skipped by the content-addressed cache
+        "gen_s": meta["gen_s"],
+        "setup_s": meta["setup_s"],
+        "trace_src": meta["trace_src"],
+    })
+    return rec
 
 
 def _run_batched(tasks: List[Dict]) -> List[Dict]:
@@ -379,11 +394,14 @@ def _run_batched(tasks: List[Dict]) -> List[Dict]:
     results: List[Dict] = []
     for members in groups.values():
         t0 = time.time()
-        built = [_build_cell(t) for t in members]
+        built = [_build_cell(t, bool(t.get("profile"))) for t in members]
         ms = BatchSim([sim for sim, _ in built]).run()
         wall = (time.time() - t0) / len(members)
-        results.extend(_cell_result(meta, m, wall)
-                       for (_, meta), m in zip(built, ms))
+        for (sim, meta), m in zip(built, ms):
+            out = _cell_result(meta, m, wall)
+            if sim.prof is not None:
+                out["profile"] = _profile_record(sim.prof, meta)
+            results.append(out)
     return results
 
 
@@ -494,9 +512,8 @@ def run_sweep(policies: Sequence[str], scenarios: Sequence[str],
     ``engine="batched"`` routes cells through the in-process
     replica-batched engine: cells sharing a resolved fleet spec run in
     lockstep with fused estimator / Algorithm-1 services and bit-identical
-    per-cell metrics (coalesce rules: :func:`_run_batched`).  Profiled
-    sweeps keep the pool/serial path — the per-component clocks are not
-    accumulated through the collect pipeline."""
+    per-cell metrics (coalesce rules: :func:`_run_batched`); a profiled
+    batched cell reports its replica's share of each lockstep round."""
     tasks = [{"policy": p, "placer": pl, "objective": ob, "scenario": sc,
               "seed": s, "fleet": fleet, "n_jobs": n_jobs, "mtbf": mtbf,
               "profile": profile, "retries": retries,
@@ -518,7 +535,7 @@ def run_sweep(policies: Sequence[str], scenarios: Sequence[str],
             tasks = fresh
     t0 = time.time()
     batched_results: List[Dict] = []
-    if engine == "batched" and tasks and not profile:
+    if engine == "batched" and tasks:
         batched_results, tasks = _run_batched(tasks), []
     if workers is None and not serial:
         # tiny grids (e.g. the CI smoke sweep) finish faster in-process than
@@ -596,7 +613,7 @@ def run_sweep(policies: Sequence[str], scenarios: Sequence[str],
             "resumed_cells": len(resumed),
             "trace_cache": trace_cache,
             "engine": engine,
-            # cells the batched engine ran (0 under --profile)
+            # cells the batched engine ran
             "batched_cells": len(batched_results),
         },
         "wall_s_total": time.time() - t0,
@@ -733,9 +750,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "coalesces cells that share a fleet spec into "
                          "one in-process lockstep replica batch with "
                          "fused estimator/Algorithm-1 services "
-                         "(bit-identical metrics; profiled sweeps keep the "
-                         "pool). On a non-CPU backend the pool refuses to "
-                         "start: use 'batched' or --serial")
+                         "(bit-identical metrics). On a non-CPU backend "
+                         "the pool refuses to start: use 'batched' or "
+                         "--serial")
     ap.add_argument("--out", default="BENCH_sweep.json",
                     help="JSON report path")
     return ap

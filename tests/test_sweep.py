@@ -622,10 +622,21 @@ def test_batched_engine_group_failure_falls_back(monkeypatch):
 
 
 def test_batched_engine_profile_falls_back():
-    """--profile keeps the scalar path (per-component clocks are not
-    accumulated through the collect pipeline) but still completes."""
-    rep = run_sweep(["miso"], ["smoke"], seeds=[0], serial=True,
+    """--profile with the batched engine runs batched, and each cell
+    reports every bucket of its replica's rounds: its own event loop,
+    stages B and D, and its shares of the fused stages A and C."""
+    from repro.core.sim.prof import KEYS
+
+    rep = run_sweep(["miso"], ["smoke"], seeds=[0, 1], serial=True,
                     profile=True, engine="batched")
-    assert rep["config"]["batched_cells"] == 0
-    (r,) = rep["results"]
-    assert "profile" in r and r["profile"]["events"] > 0
+    assert rep["config"]["batched_cells"] == 2
+    for r in rep["results"]:
+        p = r["profile"]
+        assert set(KEYS) <= set(p)
+        assert p["events"] > 0
+        assert p["collect_s"] > 0 and p["fused_estimator_s"] > 0
+        assert p["total_s"] == pytest.approx(
+            p["collect_s"] + p["round_apply_s"] + p["fused_estimator_s"]
+            + p["fused_alg1_s"])
+        assert p["estimator_s"] >= p["fused_estimator_s"]
+        assert 0 < p["event_loop_s"] < p["total_s"]
