@@ -18,11 +18,14 @@ tuples — one per co-location group / profiling window — and returns one
   same order (estimators that consume RNG draw it in request order);
 * ``mps_matrix`` may be None per request; estimators that need one measure
   it themselves (as ``estimate`` does);
-* the U-Net estimator stacks every request's matrix into a single
-  ``(B, levels, jobs)`` jitted forward (padded to a power-of-two batch
-  bucket) instead of B separate ``(1, levels, jobs)`` dispatches — the
-  engine's same-tick window coalescing is the main caller.  A batched
-  forward is numerically equal to per-request forwards up to XLA batch
+* the U-Net estimator stacks every request's matrix on the host into a
+  single ``(B, levels, jobs)`` batch, zero-padded there to its
+  power-of-two bucket, instead of B separate ``(1, levels, jobs)``
+  dispatches — the engine's same-tick window coalescing is the main
+  caller.  The forward is one device program at the bucket; it returns
+  without waiting, one readback brings every row home, and the padding
+  rows are dropped when rows are paired with requests.  A batched forward
+  is numerically equal to per-request forwards up to XLA batch
   reassociation (float32 last-ulp); single-request batches go through the
   exact same compiled shape as ``estimate`` and are bit-identical to it.
 """
@@ -175,31 +178,35 @@ class UNetEstimator:
         forward's host, wait and post-processing time go to."""
         if mps_matrix is None:
             mps_matrix = self.measure_mps(profs)
+        m = np.asarray(mps_matrix, np.float32)[None]           # (1, L, J)
         if prof is None:
-            pred = np.asarray(self.net(mps_matrix))            # (3, J)
-            return self._postprocess(profs, pred, qos)
+            return self._postprocess(profs, np.asarray(self.net(m))[0], qos)
         return self._profiled(
-            prof, lambda: self.net(mps_matrix),
-            lambda pred: self._postprocess(profs, pred, qos))
+            prof, lambda: self.net(m),
+            lambda pred: self._postprocess(profs, pred[0], qos))
 
     def estimate_batch(self, requests: Sequence[EstimateRequest], prof=None
                        ) -> List[List[Dict[int, float]]]:
         """Fused path: all B requests' matrices go through one stacked
         ``(B, levels, jobs)`` jitted forward (see module docstring for the
         numerical contract); measurement (and thus any RNG use) happens in
-        request order before the forward.  ``prof`` as in :meth:`estimate`."""
+        request order before the forward.  The stack is padded to its
+        bucket here, so the forward returns without waiting and the one
+        readback carries every row; ``zip`` in ``post`` drops the padding
+        rows.  ``prof`` as in :meth:`estimate`."""
         if not requests:
             return []
-        mats = [np.asarray(mat if mat is not None else self.measure_mps(profs),
-                           dtype=np.float32)
-                for profs, mat, _ in requests]
+        m = unet_mod.pad_to_bucket(np.stack(
+            [np.asarray(mat if mat is not None else self.measure_mps(profs),
+                        dtype=np.float32)
+             for profs, mat, _ in requests]))               # (bucket, L, J)
 
         def post(preds):
             return [self._postprocess(profs, pred, qos)
                     for (profs, _, qos), pred in zip(requests, preds)]
         if prof is None:
-            return post(np.asarray(self.net(np.stack(mats))))  # (B, 3, J)
-        return self._profiled(prof, lambda: self.net(np.stack(mats)), post)
+            return post(np.asarray(self.net(m)))            # (bucket, 3, J)
+        return self._profiled(prof, lambda: self.net(m), post)
 
     @staticmethod
     def _profiled(prof, launch, post):

@@ -16,10 +16,12 @@ training), and the output is cropped back.
 Inference goes through one module-level jitted apply shared by every
 :class:`UNet` instance (keyed on parameter shapes + input shape, so all
 estimators in a process reuse one compiled executable per shape instead of
-recompiling per instance), and batches are padded to power-of-two buckets
-so a handful of compilations serve any batch size.  Every convolution runs
-at ``PRECISION``: float32 on every backend, where the TPU's default would
-use bfloat16 passes.
+recompiling per instance), and batches are zero-padded to power-of-two
+buckets on the host (numpy) so a handful of compilations serve any batch
+size: a U-Net call is exactly one device program, ``_apply_jit`` at the
+bucket, whose own argument transfer carries the input in.  Every
+convolution runs at ``PRECISION``: float32 on every backend, where the
+TPU's default would use bfloat16 passes.
 """
 from __future__ import annotations
 
@@ -27,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.utils.tree import ParamBuilder, fan_in_init
@@ -122,6 +125,18 @@ def _bucket(b: int) -> int:
     return n
 
 
+def pad_to_bucket(m: np.ndarray) -> np.ndarray:
+    """A float32 ``(batch, levels, jobs)`` host array with zero rows
+    appended up to its power-of-two bucket; ``m`` itself if it is one."""
+    b = len(m)
+    nb = _bucket(b)
+    if nb == b:
+        return m
+    out = np.zeros((nb,) + m.shape[1:], np.float32)
+    out[:b] = m
+    return out
+
+
 def warm_jit_cache(levels: int = 3, jobs: int = 7,
                    batch_buckets=(1, 2, 4, 8)) -> None:
     """Compile the shared apply for the standard shapes ahead of time.
@@ -135,7 +150,7 @@ def warm_jit_cache(levels: int = 3, jobs: int = 7,
     # and XLA keys its compile cache on shapes, so any constant key works
     params, _ = init(jax.random.PRNGKey(0), levels, jobs)
     for b in batch_buckets:
-        m = jnp.zeros((b, levels, jobs), jnp.float32)
+        m = np.zeros((b, levels, jobs), np.float32)
         _apply_jit(params, m, levels, jobs).block_until_ready()
 
 
@@ -154,16 +169,23 @@ class UNet:
 
     def __call__(self, mps_matrix):
         """(levels, jobs) or (batch, levels, jobs) -> predictions of the same
-        leading shape.  Batches are zero-padded up to the next power-of-two
-        bucket (batch elements are independent through every conv, so padding
-        rows never change real rows) and cropped back."""
-        single = mps_matrix.ndim == 2
-        m = mps_matrix[None] if single else mps_matrix
-        b = m.shape[0]
-        nb = _bucket(b)
-        m = jnp.asarray(m, jnp.float32)
-        if nb != b:
-            m = jnp.concatenate(
-                [m, jnp.zeros((nb - b,) + m.shape[1:], jnp.float32)], axis=0)
-        out = _apply_jit(self.params, m, self.levels, self.jobs)[:b]
-        return out[0] if single else out
+        leading shape.
+
+        The input becomes float32 on the host and, if its batch is not a
+        power-of-two bucket, gets zero rows appended there (batch elements
+        are independent through every conv, so padding rows never change
+        real rows); then one ``_apply_jit`` call takes it to the device.
+        A 3-D batch that is already a bucket comes back as the device
+        array, without waiting for it; any other input is read back and
+        cropped (or, for a 2-D matrix, indexed) with numpy."""
+        m = np.asarray(mps_matrix, np.float32)
+        single = m.ndim == 2
+        if single:
+            m = m[None]
+        b = len(m)
+        padded = pad_to_bucket(m)
+        out = _apply_jit(self.params, padded, self.levels, self.jobs)
+        if padded is m and not single:
+            return out
+        out = np.asarray(out)
+        return out[0] if single else out[:b]

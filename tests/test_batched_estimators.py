@@ -2,6 +2,7 @@
 same-tick phase-end coalescing (batched runs must be bit-identical to
 sequential processing, including estimator RNG draw order)."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -86,6 +87,64 @@ def test_unet_batch_bucketing_pads_and_crops(unet_est):
                      for p in WORKLOADS[:3]])
     out = np.asarray(unet_est.net(mats))     # B=3 -> bucket 4 -> cropped
     assert out.shape == (3, 3, 7)
+
+
+def _requests(est, rng, n):
+    return [(profs, est.measure_mps(profs), qos)
+            for profs, _, qos in _mixes(rng, n)]
+
+
+def test_unet_estimate_batch_compiles_nothing_past_its_bucket(unet_est):
+    """Host-side pad and crop: once bucket 64 is warm, batches of 37 and
+    40 windows run the same one program and compile nothing (eager device
+    pad and crop compiled new programs for every batch size)."""
+    rng = np.random.default_rng(5)
+    unet_est.estimate_batch(_requests(unet_est, rng, 64))
+    batches = [_requests(unet_est, rng, n) for n in (37, 40)]
+    compiles = []
+
+    def on_duration(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        for reqs in batches:
+            assert len(unet_est.estimate_batch(reqs)) == len(reqs)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+
+
+def _device_padded(params, mats):
+    """The forward as it was before padding moved to the host: the batch
+    padded on the device to its bucket, cropped there afterwards."""
+    m, b = jnp.asarray(mats, jnp.float32), len(mats)
+    nb = unet._bucket(b)
+    if nb != b:
+        m = jnp.concatenate(
+            [m, jnp.zeros((nb - b,) + m.shape[1:], jnp.float32)], axis=0)
+    return np.asarray(unet._apply_jit(params, m, 3, 7)[:b])
+
+
+@pytest.mark.parametrize("b", [1, 3, 40])
+def test_unet_host_padding_equals_device_padding(unet_est, b, monkeypatch):
+    """The rows ``estimate_batch`` hands on and ``UNet.__call__``'s
+    outputs equal, bit for bit, the forward of a batch padded on the
+    device and cropped there afterwards."""
+    reqs = _requests(unet_est, np.random.default_rng(10 + b), b)
+    mats = np.stack([mat for _, mat, _ in reqs])
+    params = unet_est.net.params
+    want = _device_padded(params, mats)
+
+    np.testing.assert_array_equal(np.asarray(unet_est.net(mats)), want)
+    np.testing.assert_array_equal(np.asarray(unet_est.net(mats[0])),
+                                  _device_padded(params, mats[:1])[0])
+    rows = []
+    post = unet_est._postprocess
+    monkeypatch.setattr(unet_est, "_postprocess", lambda profs, pred, qos:
+                        rows.append(np.array(pred)) or post(profs, pred, qos))
+    unet_est.estimate_batch(reqs)
+    np.testing.assert_array_equal(np.stack(rows), want)
 
 
 # -------------------------------------------------- same-tick coalescing
