@@ -1,17 +1,25 @@
-"""One replica of the testbed as a plain per-event loop.
+"""One replica of the cluster as a plain per-event loop.
 
-Jobs queue first come, first served; the head goes to the GPU with fewest
-jobs (lowest id on a tie) among those that can take it: fewer jobs than
-the largest partition holds, the sum of footprints within the GPU's
-memory, and some partition that gives every job a slice it fits.  A
-placement checkpoints the GPU's jobs if they were running on slices, then
-opens an MPS window of one level time per level, during which the jobs
-progress at their mean MPS speed.  At its end the probe's matrix goes
-through the estimator and Algorithm 1 picks a partition and assignment;
-a change of layout costs a reconfiguration plus the largest job's
-checkpoint before the jobs run on their slices at their true speeds.  A
-completion re-runs Algorithm 1 on the estimates the GPU holds when it is
-running on slices, or leaves the GPU idle when it is empty.
+It states the ``miso`` policy, ``least-loaded`` placement and the
+``throughput`` objective (:data:`STATES`), and nothing else.  Each GPU has
+its group's menu, memory and speed model, estimator and U-Net weights
+(``ref.fleet``).  Work is in seconds of the reference GPU, so a GPU of
+speed scale ``s`` progresses ``s`` times as fast as its own speeds say, in
+the MPS window and on its slices alike.
+
+Jobs queue first come, first served; the head goes to the GPU with
+fewest jobs (lowest id on a tie, whatever its speed) among those that
+can take it: fewer jobs than the largest partition of its menu holds,
+the sum of footprints within the GPU's memory, and some partition of its
+menu that gives every job a slice it fits.  A placement checkpoints the
+GPU's jobs if they were running on slices, then opens an MPS window of
+one level time per level, during which the jobs progress at their mean
+MPS speed.  At its end the probe's matrix goes through the estimator and
+Algorithm 1 picks a partition and assignment; a change of layout costs a
+reconfiguration plus the largest job's checkpoint before the jobs run on
+their slices at their true speeds.  A completion re-runs Algorithm 1 on
+the estimates the GPU holds when it is running on slices, or leaves the
+GPU idle when it is empty.
 
 Two answers of the program are taken rather than made, because a float32
 forward and a near-tie in Algorithm 1 may each go either way and a single
@@ -33,7 +41,7 @@ from __future__ import annotations
 import collections
 import heapq
 import itertools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -42,6 +50,9 @@ from ref.unet import Estimator, forward
 
 IDLE, CKPT, MPS, MIG = "idle", "ckpt", "mps", "mig"
 ARRIVAL, TIMER, DONE = 0, 1, 2
+#: the configuration's policy, placer and objective this module states
+STATES = {"policy": "miso", "placer": "least-loaded",
+          "objective": "throughput"}
 
 
 class Job:
@@ -54,9 +65,17 @@ class Job:
         self.finish: Optional[float] = None
 
 
+class Kind(NamedTuple):
+    """What the GPUs of one group run with."""
+    tb: Testbed
+    est: Estimator
+    params: dict
+    scale: float
+
+
 class Gpu:
-    def __init__(self, gid: int):
-        self.gid = gid
+    def __init__(self, gid: int, kind: Kind):
+        self.gid, self.kind = gid, kind
         self.phase = IDLE
         self.phase_end = 0.0
         self.reprobe = False          # the CKPT window leads into MPS
@@ -82,9 +101,10 @@ class ProgramRecord:
 
 
 class Replica:
-    def __init__(self, tb: Testbed, est: Estimator, params, sim: dict,
+    def __init__(self, kinds: Sequence[Kind], sim: dict,
                  jobs: Sequence[Job], record: ProgramRecord):
-        self.tb, self.est, self.params, self.cfg = tb, est, params, sim
+        """``kinds``: each GPU's, by GPU id."""
+        self.kinds, self.cfg = list(kinds), sim
         self.jobs = {j.jid: j for j in jobs}
         self.record = record
         self.gpus: List[Gpu] = []
@@ -100,9 +120,9 @@ class Replica:
 
     # ------------------------------------------------------------- loop
 
-    def run(self, n_gpus: int) -> Dict[int, float]:
+    def run(self) -> Dict[int, float]:
         """Finish time of every job the replica completes."""
-        self.gpus = [Gpu(i) for i in range(n_gpus)]
+        self.gpus = [Gpu(i, k) for i, k in enumerate(self.kinds)]
         for j in self.jobs.values():
             self._push(j.arrival, ARRIVAL, j.jid, 0)
         while self.heap and self.done < len(self.jobs):
@@ -146,16 +166,14 @@ class Replica:
     # --------------------------------------------------------- placement
 
     def _admit(self):
-        tb = self.tb
-        cap = tb.hw["mem_gb"]
         while self.queue:
             job = self.jobs[self.queue[0]]
             free = [g for g in self.gpus
-                    if len(g.jobs) < tb.max_jobs
+                    if len(g.jobs) < g.kind.tb.max_jobs
                     and sum(j.prof.mem_gb for j in g.jobs)
-                    + job.prof.mem_gb <= cap
-                    and tb.fits([j.prof.mem_gb for j in g.jobs]
-                                + [job.prof.mem_gb])]
+                    + job.prof.mem_gb <= g.kind.tb.hw["mem_gb"]
+                    and g.kind.tb.fits([j.prof.mem_gb for j in g.jobs]
+                                       + [job.prof.mem_gb])]
             if not free:
                 return
             g = min(free, key=lambda g: (len(g.jobs), g.gid))
@@ -185,7 +203,8 @@ class Replica:
         c = self.cfg
         if g.phase == CKPT and g.reprobe:
             g.phase = MPS
-            g.phase_end = self.t + (len(self.tb.levels) * c["mps_level_time_s"]
+            g.phase_end = self.t + (len(g.kind.tb.levels)
+                                    * c["mps_level_time_s"]
                                     * c["overhead_scale"])
             g.reprobe = False
         elif g.phase == MPS:
@@ -195,27 +214,28 @@ class Replica:
             g.phase = MIG if g.jobs else IDLE
 
     def _probe(self, g: Gpu):
+        est, params = g.kind.est, g.kind.params
         profs = [j.prof for j in g.jobs]
-        mat = self.est.measure(profs)
+        mat = est.measure(profs)
         jids = tuple(j.jid for j in g.jobs)
         self.windows += 1
         queue = self.record.windows.get(g.gid)
         if queue and queue[0][0] == jids:
             _, _, out = queue.popleft()
             out = np.asarray(out)
-            gap = float(np.abs(out - forward(self.params, mat[None])[0]).max())
+            gap = float(np.abs(out - forward(params, mat[None])[0]).max())
             self.unet_gap = max(self.unet_gap,
                                 gap if np.isfinite(gap) else 1.0)
         else:
             self.unet_gap = 1.0
-            out = forward(self.params, mat[None])[0]
-        for j, e in zip(g.jobs, self.est.estimate(profs, out)):
+            out = forward(params, mat[None])[0]
+        for j, e in zip(g.jobs, est.estimate(profs, out)):
             g.estimates[j.jid] = e
 
     def _repartition(self, g: Gpu):
         jids = tuple(j.jid for j in g.jobs)
-        speeds = [g.estimates.get(jid, {self.tb.full: 1.0}) for jid in jids]
-        part = self._decide(g.gid, jids, speeds)
+        speeds = [g.estimates.get(jid, {g.kind.tb.full: 1.0}) for jid in jids]
+        part = self._decide(g, jids, speeds)
         old = tuple(g.slice[jid] for jid in jids)
         for jid, s in zip(jids, part):
             g.slice[jid] = s
@@ -225,13 +245,15 @@ class Replica:
         else:
             g.phase = MIG
 
-    def _decide(self, gid: int, jids: Tuple[int, ...],
+    def _decide(self, g: Gpu, jids: Tuple[int, ...],
                 speeds: Sequence[Dict[int, float]]) -> Tuple[int, ...]:
-        """Algorithm 1 by enumeration; returns the program's partition
-        when it is a valid one that scores the best, else its gap counts."""
+        """Algorithm 1 by enumeration over ``g``'s menu; returns the
+        program's partition when it is a valid one that scores the best,
+        else its gap counts."""
+        by_len = g.kind.tb.by_len
         best, feasible_best, first = -1.0, -1.0, None
         m = len(jids)
-        for part in self.tb.by_len.get(m, ()):
+        for part in by_len.get(m, ()):
             row_best, row_feasible = -1.0, False
             for perm in sorted(set(itertools.permutations(part))):
                 vals = [speeds[j].get(perm[j], 0.0) for j in range(m)]
@@ -246,13 +268,13 @@ class Replica:
                 feasible_best = max(feasible_best, row_best)
         target = feasible_best if feasible_best >= 0.0 else best
         self.decisions += 1
-        queue = self.record.decisions.get(gid)
+        queue = self.record.decisions.get(g.gid)
         if not queue or queue[0][0] != jids:
             self.alg1_gap = 1.0
             return tuple(first)
         _, part = queue.popleft()
         valid = (len(part) == m and tuple(sorted(part, reverse=True))
-                 in self.tb.by_len.get(m, ()))
+                 in by_len.get(m, ()))
         vals = [speeds[j].get(part[j], 0.0) for j in range(m)] if valid else []
         if not valid or (feasible_best >= 0.0 and min(vals) <= 0.0):
             self.alg1_gap = 1.0
@@ -270,14 +292,16 @@ class Replica:
         g.clock = self.t
 
     def _settle(self, g: Gpu):
-        """New speeds after a change on ``g``, and its next events."""
+        """New speeds after a change on ``g``, in reference-GPU seconds of
+        work a second, and its next events."""
+        tb, scale = g.kind.tb, g.kind.scale
         if g.phase == MIG:
-            g.speed = {j.jid: (self.tb.slice_speed(j.prof, g.slice[j.jid])
+            g.speed = {j.jid: (scale * tb.slice_speed(j.prof, g.slice[j.jid])
                                if g.slice[j.jid] else 0.0) for j in g.jobs}
         elif g.phase == MPS and g.jobs:
-            g.speed = dict(zip((j.jid for j in g.jobs),
-                               self.tb.mps_run_speeds([j.prof
-                                                       for j in g.jobs])))
+            g.speed = {j.jid: scale * v for j, v in
+                       zip(g.jobs, tb.mps_run_speeds([j.prof
+                                                      for j in g.jobs]))}
         else:
             g.speed = {j.jid: 0.0 for j in g.jobs}
         self._schedule(g)

@@ -1,5 +1,5 @@
-"""The testbed's GPU as its configuration states it: the MIG menu and the
-speeds a job runs at on a slice or under MPS.
+"""A GPU of one kind as its configuration's group states it: the MIG menu
+and the speeds a job runs at on a slice or under MPS.
 
 Speeds are normalized to the job alone on the whole GPU.  On a MIG slice
 a job gets the slice's share of the SMs (no more than it can use), of the
@@ -17,6 +17,12 @@ import math
 from typing import Dict, List, NamedTuple, Sequence, Tuple
 
 
+#: the constants of ``hardware`` that the speed model reads
+HARDWARE = ("peak_flops", "hbm_bw", "mem_gb", "cache_mps_kappa",
+            "cache_mig_kappa", "mps_mux_overhead", "mps_bw_loss",
+            "sched_overhead_s")
+
+
 class Profile(NamedTuple):
     name: str
     flops_per_step: float
@@ -32,10 +38,12 @@ def profile(row: dict) -> Profile:
 
 
 class Testbed:
-    def __init__(self, config: dict):
-        hw, mig = config["hardware"], config["mig"]
-        self.hw = dict(hw)
-        self.levels = tuple(config["mps_levels"])
+    """The GPUs of one group (``ref.fleet``), probed at MPS ``levels``."""
+
+    def __init__(self, group: dict, levels: Sequence[float]):
+        hw, mig = group["hardware"], group["mig"]
+        self.hw = {k: hw[k] for k in HARDWARE}
+        self.levels = tuple(levels)
         self.compute_slots = mig["compute_slots"]
         self.memory_slots = mig["memory_slots"]
         self.slices: Dict[int, dict] = {s["size"]: s for s in mig["slices"]}

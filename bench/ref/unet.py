@@ -92,16 +92,16 @@ def forward(params, mats) -> np.ndarray:
 
 
 class Estimator:
-    """The probe's measurement and the estimate built from a U-Net output."""
+    """The probe's measurement and the estimate built from a U-Net output,
+    on one group's testbed, with that group's ``predictor`` and heads."""
 
-    def __init__(self, testbed: Testbed, config: dict, heads: np.ndarray):
-        pred = config["predictor"]
+    def __init__(self, testbed: Testbed, pred: dict, pad: dict,
+                 heads: np.ndarray):
         self.tb = testbed
         self.jobs = pred["jobs"]
         self.unet_slices = tuple(pred["unet_slices"])
         self.linreg_slices = tuple(pred["linreg_slices"])
-        self.pad = Profile(**{k: config["pad_profile"][k]
-                              for k in Profile._fields})
+        self.pad = Profile(**{k: pad[k] for k in Profile._fields})
         self.heads = heads
 
     def measure(self, profs: Sequence[Profile]) -> np.ndarray:

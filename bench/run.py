@@ -9,12 +9,19 @@ and a traffic mix (``bench/traffic/<traffic>.json``), and each metric of the
 cell is read by ``bench/metrics/<metric>.py``.  Nothing here branches on a
 cell.
 
+Before any work, the configuration is checked against what the reference
+states (``bench/ref/``): its policy, placer and objective, and for each
+group of its fleet the program's spec of that kind (the MIG menu, the
+speed model's constants and the speed scale).  A mismatch exits non-zero
+and names the key.
+
 Set-up (``setup_s``) runs from the start of this script to the first timed
-replay: JAX and the chip, the program's fleet with the benchmark's copy of
-the predictor's weights, the run's traces dealt from ``--seed`` and the
-program's jobs made from them, and the U-Net's programs at every batch size
-the mix sends, taken from the persistent compilation cache in
-``.jax_cache/`` of the checkout.  The window then runs whole replays back to
+replay: JAX and the chip, the program's fleet with each group's GPUs on an
+estimator with the benchmark's copy of that group's predictor weights, the
+run's traces dealt from ``--seed`` and the program's jobs made from them,
+and each group's U-Net programs at every batch size the mix sends, taken
+from the persistent compilation cache in ``.jax_cache/`` of the
+checkout.  The window then runs whole replays back to
 back, a closed loop: replay ``r`` builds ``replicas`` fresh ``ClusterSim``
 replicas, replica ``i`` from a deep copy of trace ``(r * replicas + i) mod
 traces``, and drives them through ``BatchSim.run()``; the window ends at the
@@ -102,40 +109,117 @@ def require_chips(n: int):
     return devs[0]
 
 
-def program_fleet(config: dict, root: str):
-    """The program's fleet for the configuration: its accelerator kind,
-    with an estimator on the benchmark's copy of the predictor's weights."""
+def program_spec(kind: str):
+    """The program's spec of ``kind`` as its factory in ``FLEET_KINDS``
+    builds it, with no predictor artifact: loading one would start JAX."""
+    from repro.core import fleet
+
+    make = fleet.FLEET_KINDS[kind]
+    make = getattr(make, "__wrapped__", make)   # past the factory's memo
+    artifact_path = fleet.default_artifact_path
+    fleet.default_artifact_path = lambda kind: None
+    try:
+        return make()
+    finally:
+        fleet.default_artifact_path = artifact_path
+
+
+def spec_gaps(group: dict, spec):
+    """``(key, stated, program's)`` for each key of ``group`` that the
+    reference reads and the program's ``spec`` holds otherwise: the speed
+    scale, the MIG menu and the hardware constants."""
+    from ref.testbed import HARDWARE
+
+    mig, space, hw = group["mig"], spec.space, spec.pm.hw
+    yield "speed_scale", group["speed_scale"], spec.speed_scale
+    yield "mig.compute_slots", mig["compute_slots"], space.total_compute
+    yield "mig.memory_slots", mig["memory_slots"], space.total_mem
+    yield ("mig.exclusions", sorted(sorted(e) for e in mig["exclusions"]),
+           sorted(sorted(e) for e in space.exclusions))
+    stated = {s["size"]: s for s in mig["slices"]}
+    yield "mig.slices (sizes)", sorted(stated), sorted(space.sizes)
+    for size, st in sorted(space.slices.items()):
+        s = stated[size]
+        for key, theirs in (("name", st.name),
+                            ("compute_slots", st.compute_slots),
+                            ("memory_slots", st.mem_slots),
+                            ("memory_gb", st.memory_gb),
+                            ("max_count", st.max_count),
+                            ("cache_frac", st.cache_frac)):
+            yield f"mig.slices[{size}].{key}", s.get(key), theirs
+    for key in HARDWARE:
+        yield f"hardware.{key}", group["hardware"].get(key), getattr(hw, key)
+
+
+def check_config(config: dict) -> list:
+    """The fleet's groups with the program's spec of each, or exit before
+    any work where the configuration asks for what the reference does not
+    state or states a group otherwise than the program's spec."""
+    from ref.fleet import groups
+    from ref.sim import STATES
+    from repro.core.fleet import FLEET_KINDS
+
+    for key, want in STATES.items():
+        if config.get(key) != want:
+            sys.exit(f"bench: {key} {config.get(key)!r}: the reference "
+                     f"states only {want!r}; nothing was run")
+    try:
+        fleet = groups(config)
+    except ValueError as e:
+        sys.exit(f"bench: {e}; nothing was run")
+    out = []
+    for i, g in enumerate(fleet):
+        where = f"fleet group {i} ({g['kind']})"
+        if g["kind"] not in FLEET_KINDS:
+            sys.exit(f"bench: {where}: kind is none of the program's "
+                     f"{sorted(FLEET_KINDS)}; nothing was run")
+        spec = program_spec(g["kind"])
+        for key, stated, theirs in spec_gaps(g, spec):
+            if stated != theirs:
+                sys.exit(f"bench: {where}: {key} is {stated!r} in the "
+                         f"configuration and {theirs!r} in the program's "
+                         f"spec; nothing was run")
+        out.append((g, spec))
+    return out
+
+
+def program_fleet(groups: list, root: str):
+    """The program's fleet: each group's GPUs on its spec, with an
+    estimator on the benchmark's copy of the group's predictor weights, in
+    GPU-id order; and each group's U-Net."""
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core.estimators import UNetEstimator
-    from repro.core.fleet import FLEET_KINDS
 
-    pred = config["predictor"]
-    with np.load(os.path.join(root, pred["weights"])) as z:
-        params = {k: jnp.asarray(z[k]) for k in z.files
-                  if not k.startswith("__")}
-        heads = {"w": np.asarray(z["__head_w"]),
-                 "r2": np.asarray(z["__head_r2"])}
-    spec = FLEET_KINDS[config["kind"]]()
-    est = UNetEstimator(spec.pm, params, heads, jobs=pred["jobs"])
-    spec = dataclasses.replace(spec, estimator=est, artifact=None)
-    return [spec] * config["gpus"], est.net
+    fleet, nets = [], []
+    for g, spec in groups:
+        pred = g["predictor"]
+        with np.load(os.path.join(root, pred["weights"])) as z:
+            params = {k: jnp.asarray(z[k]) for k in z.files
+                      if not k.startswith("__")}
+            heads = {"w": np.asarray(z["__head_w"]),
+                     "r2": np.asarray(z["__head_r2"])}
+        est = UNetEstimator(spec.pm, params, heads, jobs=pred["jobs"])
+        fleet += [dataclasses.replace(spec, estimator=est)] * g["gpus"]
+        nets.append(est.net)
+    return fleet, nets
 
 
-def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
-             dev, trace_dir: str, root: str):
+def run_cell(cell: dict, groups: list, seed: int, seconds: float,
+             trace: bool, dev, trace_dir: str, root: str):
     """Set up, run the window, check; returns what the readers read."""
     from probe import Probe
 
     probe = Probe(spans=trace)
     try:
-        return _run(cell, seed, seconds, trace, dev, trace_dir, probe, root)
+        return _run(cell, groups, seed, seconds, trace, dev, trace_dir,
+                    probe, root)
     finally:
         probe.close()
 
 
-def _run(cell, seed, seconds, trace, dev, trace_dir, probe, root):
+def _run(cell, groups, seed, seconds, trace, dev, trace_dir, probe, root):
     import jax
     import numpy as np
 
@@ -148,17 +232,18 @@ def _run(cell, seed, seconds, trace, dev, trace_dir, probe, root):
     from traffic import traces as deal
 
     config, traffic = cell["config_data"], cell["traffic_data"]
-    fleet, net = program_fleet(config, root)
+    fleet, nets = program_fleet(groups, root)
     pool = [JobProfile(**row) for row in config["workloads"]]
     traces = deal(traffic, len(pool), seed)
     jobs = [[Job(jid=i, profile=pool[int(p)], arrival=float(a), work=float(w))
              for i, (p, a, w) in enumerate(zip(tr["pick"], tr["arrival"],
                                                tr["work"]))]
             for tr in traces]
-    levels, cols = net.levels, net.jobs
-    for b in range(1, traffic["unet_batch_max"] + 1):
-        np.asarray(net(np.zeros((b, levels, cols), np.float32)))
-    np.asarray(net(np.zeros((levels, cols), np.float32)))
+    for net in nets:
+        levels, cols = net.levels, net.jobs
+        for b in range(1, traffic["unet_batch_max"] + 1):
+            np.asarray(net(np.zeros((b, levels, cols), np.float32)))
+        np.asarray(net(np.zeros((levels, cols), np.float32)))
     b_rep, n_tr = traffic["replicas"], len(traces)
     cfgs = [SimConfig(n_gpus=len(fleet), policy=config["policy"],
                       placer=config["placer"], objective=config["objective"],
@@ -269,6 +354,7 @@ def main(argv=None, root: str = ROOT, chips_check=require_chips) -> int:
         ap.error("--seconds must be > 0")
     cell = load_cell(root, args.workload, bool(args.trace))
     prepare()
+    groups = check_config(cell["config_data"])
     dev = chips_check(cell["chips"])
     from repro.launch import compile_cache
     compile_cache.enable()
@@ -277,8 +363,8 @@ def main(argv=None, root: str = ROOT, chips_check=require_chips) -> int:
     import check
 
     with tempfile.TemporaryDirectory(prefix="bench_trace_") as tdir:
-        run = run_cell(cell, args.seed, args.seconds, bool(args.trace), dev,
-                       tdir, root)
+        run = run_cell(cell, groups, args.seed, args.seconds,
+                       bool(args.trace), dev, tdir, root)
     chk = run.check
     log(f"replays {run.replays} in {run.elapsed_s!r} s; events {run.events}; "
         f"jobs {run.jobs} of {run.offered}; U-Net calls {run.unet_calls}, "

@@ -11,8 +11,10 @@ import sys
 
 import pytest
 
-from benchutil import (REPO, SMALL_CELL, SMALL_TRAFFIC, load_benchmark,
-                       make_root, run_small)
+from benchutil import (BENCH, REPO, SMALL_CELL, SMALL_TRAFFIC, TWO_KIND_CELL,
+                       TWO_KIND_TRAFFIC, load_benchmark, make_root,
+                       run_small, two_kind_config)
+from ref.fleet import groups  # on the path through benchutil
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -86,7 +88,57 @@ def test_config_files_state_their_cuts():
         assert data["name"] == c["name"] and data["source"] == c["source"]
         assert sorted(data["reduced"]) == sorted(c["reduced"])
         assert all(k in data for k in c["reduced"])
-        assert os.path.isfile(os.path.join(REPO, data["predictor"]["weights"]))
+        for g in groups(data):
+            assert os.path.isfile(os.path.join(REPO,
+                                               g["predictor"]["weights"]))
+
+
+def test_a_fleet_of_one_kind_reads_as_one_group():
+    with open(os.path.join(BENCH, "configs", "miso-testbed.json")) as fh:
+        config = json.load(fh)
+    assert groups(config) == [{
+        "kind": "a100", "gpus": 8, "speed_scale": 1.0,
+        "mig": config["mig"], "hardware": config["hardware"],
+        "predictor": config["predictor"]}]
+    mixed = groups(two_kind_config())
+    assert [(g["kind"], g["gpus"], g["speed_scale"]) for g in mixed] == [
+        ("a100", 2, 1.0), ("h100", 2, 2.0)]
+    assert mixed[0]["mig"] == config["mig"]
+
+
+def _h100_with_a100_menu(config):
+    config["fleet"][1]["mig"] = config["fleet"][0]["mig"]
+
+
+def _h100_at_a100_speed(config):
+    config["fleet"][1]["speed_scale"] = 1.0
+
+
+def _speed_aware_placer(config):
+    config["placer"] = "hetero-speed"
+
+
+@pytest.mark.parametrize("edit,key", [
+    (_h100_with_a100_menu, "mig.slices[1].name"),
+    (_h100_at_a100_speed, "speed_scale"),
+    (_speed_aware_placer, "placer")])
+def test_a_configuration_the_reference_does_not_state_exits_first(
+        tmp_path, edit, key):
+    import run
+
+    config = two_kind_config()
+    edit(config)
+    root = make_root(tmp_path, [TWO_KIND_CELL], {config["name"]: config},
+                     {TWO_KIND_TRAFFIC["name"]: TWO_KIND_TRAFFIC})
+
+    def no_chip(n):
+        raise AssertionError("reached the chip check")
+
+    with pytest.raises(SystemExit) as exit_:
+        run.main(["--workload", TWO_KIND_CELL["name"], "--seed", "1",
+                  "--seconds", "0.1"], root=root, chips_check=no_chip)
+    msg = str(exit_.value.code)
+    assert key in msg and "nothing was run" in msg, msg
 
 
 def _run_script(cwd, env_extra):
