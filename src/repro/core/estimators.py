@@ -183,7 +183,7 @@ class UNetEstimator:
             return self._postprocess(profs, np.asarray(self.net(m))[0], qos)
         return self._profiled(
             prof, lambda: self.net(m),
-            lambda pred: self._postprocess(profs, pred[0], qos))
+            lambda pred: self._postprocess(profs, pred[0], qos), 1)
 
     def estimate_batch(self, requests: Sequence[EstimateRequest], prof=None
                        ) -> List[List[Dict[int, float]]]:
@@ -206,13 +206,14 @@ class UNetEstimator:
                     for (profs, _, qos), pred in zip(requests, preds)]
         if prof is None:
             return post(np.asarray(self.net(m)))            # (bucket, 3, J)
-        return self._profiled(prof, lambda: self.net(m), post)
+        return self._profiled(prof, lambda: self.net(m), post, len(requests))
 
     @staticmethod
-    def _profiled(prof, launch, post):
-        """One forward, timed: ``launch()`` returns as soon as the forward
-        is enqueued (JAX dispatches asynchronously), the readback waits for
-        it, then ``post`` turns the output into estimates."""
+    def _profiled(prof, launch, post, windows: int):
+        """One forward of ``windows`` MPS windows, timed: ``launch()``
+        returns as soon as the forward is enqueued (JAX dispatches
+        asynchronously), the readback waits for it, then ``post`` turns the
+        output into estimates."""
         from repro.core.sim.prof import timed   # core.sim imports this module
         with timed(prof, "unet_host_s", "unet.host"):
             out = launch()
@@ -221,6 +222,7 @@ class UNetEstimator:
         with timed(prof, "estimator_post_s", "estimator.post"):
             ests = post(pred)
         prof["unet_calls"] += 1.0
+        prof["unet_windows"] += windows
         return ests
 
     def _postprocess(self, profs, pred: np.ndarray,

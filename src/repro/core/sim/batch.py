@@ -246,8 +246,8 @@ class BatchSim:
         their noise draws) already happened at collect time inside each
         replica; the forward is pure, so cross-replica fusion is exact.
 
-        Profiled replicas get the stage's time and the estimator's buckets
-        in proportion to the windows each sent."""
+        Profiled replicas get the stage's time, the estimator's buckets
+        and the stage's counters in proportion to the windows each sent."""
         if not works:
             return
         owners = [w.prof for w in works]
@@ -272,17 +272,17 @@ class BatchSim:
         class that overrides ``choose_partition`` keeps its own per-decision
         logic (same guard the scalar batch path applies).
 
-        Profiled replicas get the stage's time in proportion to the
-        decisions each sent."""
+        Profiled replicas get the stage's time and counters in proportion
+        to the decisions each sent."""
         if not decisions:
             return
         owners = [d.policy.sim.prof for d in decisions]
         if not any(owners):
-            _solve_groups(decisions)
+            _solve_groups(decisions, None)
             return
         scratch = new_prof()
         with timed(scratch, "fused_alg1_s", "batch.stage_c"):
-            _solve_groups(decisions)
+            _solve_groups(decisions, scratch)
         split(scratch, owners)
 
     # --------------------------------------------------------- observation
@@ -313,9 +313,12 @@ def _estimate_groups(works: List[EstimateWork], prof) -> None:
         ests = group[0].g.estimator.estimate_batch(requests, prof=prof)
         for w, est in zip(group, ests):
             w.ests = est
+    if prof is not None:
+        prof["stage_a_runs"] += 1.0
+        prof["stage_a_forwards"] += len(by_est)
 
 
-def _solve_groups(decisions: List[RepartDecision]) -> None:
+def _solve_groups(decisions: List[RepartDecision], prof) -> None:
     groups: Dict[tuple, List[RepartDecision]] = {}
     for d in decisions:
         pol = d.policy
@@ -335,3 +338,6 @@ def _solve_groups(decisions: List[RepartDecision]) -> None:
         for d, c in zip(group, first):
             d.choice = c if c is not None else optimize_partition(
                 space, d.speeds, objective=objective, power=power)
+    if prof is not None:
+        prof["stage_c_runs"] += 1.0
+        prof["stage_c_solves"] += len(groups)

@@ -26,13 +26,25 @@ the host's spans sit on the device trace's clock.
   memory/QoS monitor;
 * ``unet_calls`` — U-Net forwards;
 * ``fused_alg1_s`` (``batch.stage_c``) — ``BatchSim`` stage C, whole;
-* ``round_apply_s`` (``batch.apply``) — ``BatchSim`` stages B and D.
+* ``round_apply_s`` (``batch.apply``) — ``BatchSim`` stages B and D;
+* ``unet_windows`` — MPS windows the U-Net's forwards carried, padding
+  rows not counted;
+* ``stage_a_runs`` — stage A runs (each had windows);
+* ``stage_a_forwards`` — ``estimate_batch`` calls inside them, one per
+  estimator object, so one per weight set in a fleet of several kinds;
+* ``stage_c_runs`` — stage C runs (each had decisions);
+* ``stage_c_solves`` — stacked ``optimize_partition_batch`` calls inside
+  them, one per (MIG menu, power model, objective).
 
 Nesting: placement, the inline Algorithm 1 and estimator and the MPS probe
 run inside ``collect_s`` or ``round_apply_s``; the U-Net and
 post-processing buckets inside ``fused_estimator_s`` or ``estimator_s``.
 ``collect_s``, ``fused_estimator_s``, ``fused_alg1_s`` and
 ``round_apply_s`` are disjoint and together make up a replica's rounds.
+Stage A's and stage C's buckets and counters are split across the
+replicas they served (:func:`split`), so ``stage_a_forwards /
+stage_a_runs`` over a batch's replicas is the mean number of U-Net
+groups a stage A ran.
 """
 from __future__ import annotations
 
@@ -44,7 +56,8 @@ import jax
 KEYS = ("placement_s", "alg1_s", "estimator_s", "total_s", "events",
         "collect_s", "mps_measure_s", "fused_estimator_s", "unet_host_s",
         "unet_wait_s", "estimator_post_s", "unet_calls", "fused_alg1_s",
-        "round_apply_s")
+        "round_apply_s", "unet_windows", "stage_a_runs", "stage_a_forwards",
+        "stage_c_runs", "stage_c_solves")
 
 
 def new_prof() -> Dict[str, float]:
