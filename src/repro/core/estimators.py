@@ -37,7 +37,7 @@ import numpy as np
 
 from repro.core.jobs import DUMMY_PROFILE, JobProfile
 from repro.core.partitions import PartitionSpace
-from repro.core.perfmodel import PerfModel
+from repro.core.perfmodel import MPS_LEVELS, PerfModel
 from repro.core.predictor import linreg as linreg_mod
 from repro.core.predictor import unet as unet_mod
 from repro.core.predictor.dataset import LIN_SLICES, OUT_SLICES
@@ -157,20 +157,63 @@ class UNetEstimator:
         Pass the simulator's ``rng`` so successive windows draw independent
         noise; without one, an instance-local stream is used (it advances
         across calls — noise is never identical between windows).
+
+        Its two halves, :meth:`draw_mps_noise` and :meth:`apply_mps_noise`
+        around the noise-free matrix, may run apart: ``BatchSim`` draws the
+        noise in event order and measures a round's windows together
+        (:meth:`measure_mps_many`).
         """
+        noise = self.draw_mps_noise(profs, noise_sigma, rng)
+        return self.apply_mps_noise(self.pm.mps_matrix(self.padded(profs)),
+                                    noise)
+
+    def draw_mps_noise(self, profs: Sequence[JobProfile],
+                       noise_sigma: float = 0.0,
+                       rng: Optional[np.random.Generator] = None
+                       ) -> Optional[np.ndarray]:
+        """The half of :meth:`measure_mps` that must run in event order:
+        the size check, then the window's ``(levels, jobs)`` relative noise
+        from ``rng`` (None where ``noise_sigma`` is 0)."""
         if len(profs) > self.jobs:
             raise ValueError(
                 f"cannot profile {len(profs)} co-located jobs: this predictor "
                 f"was trained on matrices of at most {self.jobs} columns")
-        padded = list(profs) + [DUMMY_PROFILE] * (self.jobs - len(profs))
-        m = np.asarray(self.pm.mps_matrix(padded), dtype=np.float32)
         if noise_sigma > 0:
             if rng is None:
                 rng = self._rng
-            m = m * (1.0 + rng.normal(0.0, noise_sigma, size=m.shape)
-                     ).astype(np.float32)
+            return rng.normal(0.0, noise_sigma,
+                              size=(len(MPS_LEVELS), self.jobs))
+        return None
+
+    def padded(self, profs: Sequence[JobProfile]) -> list:
+        """``profs`` and ``DUMMY_PROFILE`` columns up to ``jobs``."""
+        return list(profs) + [DUMMY_PROFILE] * (self.jobs - len(profs))
+
+    @staticmethod
+    def apply_mps_noise(mat, noise: Optional[np.ndarray]) -> np.ndarray:
+        """The other half: the padded mix's noise-free matrix in float32,
+        times ``1 + noise`` floored at 1e-6, each column over its max.
+        Leading axes, if any, stack windows."""
+        m = np.asarray(mat, dtype=np.float32)
+        if noise is not None:
+            m = m * (1.0 + noise).astype(np.float32)
             m = np.maximum(m, 1e-6)
-        return m / np.maximum(m.max(axis=0, keepdims=True), 1e-9)
+        return m / np.maximum(m.max(axis=-2, keepdims=True), 1e-9)
+
+    def measure_mps_many(self, mixes: Sequence[Sequence[JobProfile]],
+                         noises: Sequence[Optional[np.ndarray]],
+                         prof=None) -> List[np.ndarray]:
+        """:meth:`measure_mps` of windows whose noise is drawn: every
+        noise-free matrix through one ``PerfModel.mps_matrices`` call
+        (``prof`` counts its rows there), then :meth:`apply_mps_noise` on
+        them all at once, or one by one where only some have noise."""
+        mats = np.asarray(self.pm.mps_matrices(
+            [self.padded(p) for p in mixes], prof), dtype=np.float32)
+        drawn = [n is not None for n in noises]
+        if any(drawn) and not all(drawn):
+            return [self.apply_mps_noise(m, n) for m, n in zip(mats, noises)]
+        return list(self.apply_mps_noise(
+            mats, np.stack(noises) if any(drawn) else None))
 
     def estimate(self, profs, mps_matrix: Optional[np.ndarray] = None,
                  qos=None, prof=None) -> List[Dict[int, float]]:

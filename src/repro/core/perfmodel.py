@@ -19,13 +19,17 @@ trains on measured pairs.
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
-from typing import Sequence
+from typing import Dict, List, Optional, Sequence
+
+import numpy as np
 
 from repro.core.jobs import JobProfile
 from repro.core.partitions import PartitionSpace
 
 MPS_LEVELS = (1.00, 0.50, 0.14)       # paper §4.1: 100 / 50 / 14 %
+MPS_ITERS = 12                        # blended fixed-point iterations
 
 
 @dataclass(frozen=True)
@@ -52,6 +56,41 @@ TPU_V5E_POD = Hardware("tpu-v5e-pod", peak_flops=256 * 197e12,
 
 _CACHE_MAX = 65536   # FIFO bound: profiles are deepcopied per job, so keys
                      # accumulate across long sweeps without one
+
+#: ``mps_matrices`` solves the memo misses of one mix length in one
+#: stacked numpy pass from this many (mix, level) rows up, and one by one
+#: through ``mps_speeds`` below it.  The stacked pass costs nearly the
+#: same at 1 row as at 20 (its cost is numpy's per-call overhead); a row
+#: solved one by one costs its own loop.  Where they cross, on the host
+#: CPU of a TPU v5e machine (testbed mixes padded to 7 jobs, medians of
+#: 150 fresh draws): stacked 423 us at 5 rows and 426 at 6, one by one
+#: 412 and 488.
+MPS_STACK_MIN_ROWS = 6
+
+_MPS_FIELDS = ("cache_sens", "bytes_per_step", "sm_util", "flops_per_step",
+               "compute_eff")
+
+
+def pysum(x: np.ndarray) -> np.ndarray:
+    """``sum()`` down the first axis of ``x``, bit for bit as CPython 3.12
+    sums Python floats from its start of 0: the running sum, with each
+    step's rounding error accumulated apart (Neumaier) and added back at
+    the end where it is finite.  CPython takes each error by a branch on
+    the magnitudes (Fast2Sum); TwoSum here gives the same double, as both
+    are the exact error of the step.  ``+ 0.0`` maps a running sum of -0.0
+    to 0.0, as CPython's int start does, so a zero error adds nothing."""
+    if len(x) == 0:
+        return np.zeros(x.shape[1:])
+    f = np.add.accumulate(x)
+    t = f[-1] + 0.0
+    if len(x) == 1:
+        return t
+    a, s = f[:-1], f[1:]
+    bb = s - a
+    c = np.add.accumulate((a - (s - bb)) + (x[1:] - bb))[-1]
+    if not np.isfinite(c).all():
+        c = np.where(np.isfinite(c), c, 0.0)
+    return t + c
 
 
 class PerfModel:
@@ -131,7 +170,7 @@ class PerfModel:
     # ----------------------------------------------------------- MPS side
 
     def mps_speeds(self, profs: Sequence[JobProfile], level: float,
-                   iters: int = 12) -> list:
+                   iters: int = MPS_ITERS) -> list:
         """Normalized speeds (vs. solo full-GPU) for jobs co-located in MPS at
         ``level`` active-thread fraction each.  The fixed point is memoized
         on the (profiles, level) mix — a GPU's MPS window asks for the same
@@ -193,3 +232,111 @@ class PerfModel:
     def mps_matrix(self, profs: Sequence[JobProfile]) -> list:
         """3 x m matrix of MPS speeds (rows = MPS_LEVELS)."""
         return [self.mps_speeds(profs, lv) for lv in MPS_LEVELS]
+
+    def mps_matrices(self, mixes: Sequence[Sequence[JobProfile]],
+                     prof: Optional[Dict[str, float]] = None
+                     ) -> List[List[list]]:
+        """``mps_matrix`` of every mix: the same values, in rows shared
+        with the memo (read-only).  The memo answers what it holds; the
+        rows it misses are solved as rows of one array per mix length by
+        :meth:`_mps_speeds_stacked` where that length has
+        ``MPS_STACK_MIN_ROWS`` of them, else one by one by
+        :meth:`mps_speeds`.  Solved rows enter the memo as ``mps_speeds``
+        enters them, so later scalar calls hit.  ``prof`` (profile
+        buckets, ``core/sim/prof.py``) counts the rows asked for, those
+        the memo answered (a row asked for twice is answered the second
+        time) and those solved stacked."""
+        cache = self._mps_cache
+        found: Dict[tuple, list] = {}      # memo key -> row
+        todo: Dict[tuple, tuple] = {}      # memo key -> (profs, level)
+        keys_of = []
+        for profs in mixes:
+            ids = tuple(map(id, profs))
+            keys = [(ids, lv, MPS_ITERS) for lv in MPS_LEVELS]
+            for key, lv in zip(keys, MPS_LEVELS):
+                if key in found or key in todo:
+                    continue
+                hit = cache.get(key)
+                if hit is not None:
+                    found[key] = hit[1]
+                else:
+                    todo[key] = (profs, lv)
+            keys_of.append(keys)
+        stacked = 0
+        by_len: Dict[int, list] = {}
+        for key, (profs, _) in todo.items():
+            by_len.setdefault(len(profs), []).append(key)
+        for keys in by_len.values():
+            if len(keys) < MPS_STACK_MIN_ROWS:
+                continue
+            rates = self._mps_speeds_stacked([todo[k][0] for k in keys],
+                                             [todo[k][1] for k in keys])
+            if rates is None:
+                continue
+            stacked += len(keys)
+            for k, row in zip(keys, rates.tolist()):
+                self._bound(cache)
+                cache[k] = (tuple(todo[k][0]), row)
+                found[k] = row
+        for key, (profs, lv) in todo.items():
+            if key not in found:
+                found[key] = self.mps_speeds(profs, lv)
+        if prof is not None:
+            asked = len(MPS_LEVELS) * len(mixes)
+            prof["mps_rows"] += asked
+            prof["mps_rows_hit"] += asked - len(todo)
+            prof["mps_rows_stacked"] += stacked
+        return [[found[k] for k in keys] for keys in keys_of]
+
+    def _mps_speeds_stacked(self, mixes: Sequence[Sequence[JobProfile]],
+                            levels: Sequence[float]) -> Optional[np.ndarray]:
+        """``mps_speeds`` of many (mix, level) rows whose mixes share one
+        length m, as an (rows, m) float64 array equal to it bit for bit:
+        each of its float operations runs here on a whole column, in its
+        order, and its ``sum()``s are :func:`pysum`.  None where that does
+        not hold: an empty mix, a profile field that is not a Python float
+        (``sum()`` adds ints and float subclasses differently), or a
+        profile with no full-GPU speed (``mps_speeds`` raises there)."""
+        hw = self.hw
+        m = len(mixes[0])
+        # each mix once (its levels are rows that share it), each profile
+        # once; the arrays run (job, row)
+        at = {id(ps): ps for ps in mixes}
+        objs = list({id(p): p for ps in at.values() for p in ps}.values())
+        vals = [[getattr(p, f) for p in objs] for f in _MPS_FIELDS]
+        if set(map(type, itertools.chain.from_iterable(vals))) != {float}:
+            return None
+        col = {id(p): k for k, p in enumerate(objs)}
+        cols = {i: [col[id(p)] for p in ps] for i, ps in at.items()}
+        full = self.space.full_size
+        cs, byt, sm, fl, eff, t_solo = np.array(
+            vals + [[self.slice_time(p, full) for p in objs]])[
+                :, np.array([cols[id(ps)] for ps in mixes]).T]
+        solo = 1.0 / t_solo
+        if not solo.all():
+            return None
+        # cache pressure from co-runners: job i sums the others in order
+        others = pysum(cs[np.array(
+            [[j for j in range(m) if j != i] for i in range(m)],
+            dtype=np.intp).reshape(m, m - 1).T])
+        pressures = np.minimum(2.0, others / 2.0)
+        bytes_eff = byt * (1.0 + hw.cache_mps_kappa * cs * pressures)
+        caps = np.minimum(np.array(levels), sm)
+        shares = caps / np.maximum(1.0, pysum(caps))
+        bw_total = hw.hbm_bw * max(0.4, 1.0 - hw.mps_bw_loss * (m - 1))
+        t_comps = fl / (hw.peak_flops * shares * eff)
+        mux = 1.0 + hw.mps_mux_overhead * (m - 1)
+        overhead = hw.sched_overhead_s
+        # ``total_d > bw_total and total_d > 0`` in one comparison
+        bw_floor = max(bw_total, 0.0)
+        rates = solo
+        for _ in range(MPS_ITERS):
+            demand = rates * bytes_eff
+            total_d = pysum(demand)
+            bw_i = np.where(total_d > bw_floor, bw_total * demand / total_d,
+                            bw_total)
+            t_mem = bytes_eff / np.maximum(bw_i, 1e-6)
+            new_rates = 1.0 / (np.maximum(t_comps, t_mem) * mux + overhead)
+            rates = 0.5 * rates + 0.5 * new_rates
+        return (rates / solo).T
+

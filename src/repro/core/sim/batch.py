@@ -19,6 +19,8 @@ advances all B replicas in lockstep rounds instead:
   the fused services:
 
   - **stage A** — every collected MPS window, grouped by estimator object,
+    gets its noise-free MPS matrix from one stacked fixed-point pass
+    (``PerfModel.mps_matrices``; the noise was drawn at collect time) and
     goes through one ``estimate_batch`` call: a single stacked
     ``(sum B_i, levels, jobs)`` predictor forward for the whole round;
   - **stage B** — each pending resumes its tick (store estimates, run
@@ -32,12 +34,13 @@ advances all B replicas in lockstep rounds instead:
     completing the tick exactly as the scalar engine would.
 
 Bit-identity: replicas share nothing mutable but deterministic pure caches
-(optimizer memo, space feasibility caches) whose values are
-order-independent, every noise draw happens at collect time inside its own
-replica in event order, and the fused services are element-exact twins of
-their scalar counterparts — so each replica's metrics are bit-identical to
-running it alone through ``ClusterSim.run()``.  ``tests/test_batch.py``
-holds that property over the golden traces.
+(optimizer memo, space feasibility caches, the perf model's MPS memo)
+whose values are order-independent, every noise draw happens at collect
+time inside its own replica in event order, and the fused services are
+element-exact twins of their scalar counterparts — so each replica's
+metrics are bit-identical to running it alone through
+``ClusterSim.run()``.  ``tests/test_batch.py`` holds that property over
+the golden traces.
 
 Cross-replica fusion needs shared spec objects: build replicas against the
 same ``GPUSpec`` list (the sweep runner's fleet cache already does this)
@@ -241,10 +244,11 @@ class BatchSim:
 
     @staticmethod
     def _fuse_estimates(works: List[EstimateWork]) -> None:
-        """Stage A: fill ``w.ests`` for every collected MPS window via one
-        ``estimate_batch`` call per estimator object.  Measurements (and
-        their noise draws) already happened at collect time inside each
-        replica; the forward is pure, so cross-replica fusion is exact.
+        """Stage A: fill ``w.mat`` and ``w.ests`` for every collected MPS
+        window, per estimator object one stacked MPS fixed-point pass and
+        one ``estimate_batch`` call.  The noise draws already happened at
+        collect time inside each replica, in event order; the fixed point
+        and the forward are pure, so cross-replica fusion is exact.
 
         Profiled replicas get the stage's time, the estimator's buckets
         and the stage's counters in proportion to the windows each sent."""
@@ -309,13 +313,30 @@ def _estimate_groups(works: List[EstimateWork], prof) -> None:
     for w in works:
         by_est.setdefault(id(w.g.estimator), []).append(w)
     for group in by_est.values():
+        est = group[0].g.estimator
+        todo = [w for w in group if w.mat is None]
+        if todo and getattr(est, "needs_mps", False):
+            _measure_group(est, todo, prof)
         requests = [(w.profs, w.mat, w.qos) for w in group]
-        ests = group[0].g.estimator.estimate_batch(requests, prof=prof)
-        for w, est in zip(group, ests):
-            w.ests = est
+        for w, ests in zip(group, est.estimate_batch(requests, prof=prof)):
+            w.ests = ests
     if prof is not None:
         prof["stage_a_runs"] += 1.0
         prof["stage_a_forwards"] += len(by_est)
+
+
+def _measure_group(est, works: List[EstimateWork], prof) -> None:
+    """The MPS probe of an estimator's windows that have no matrix yet,
+    less the noise drawn at collect time: every noise-free matrix through
+    one stacked fixed-point pass (``UNetEstimator.measure_mps_many``)."""
+    mixes, noises = [w.profs for w in works], [w.noise for w in works]
+    if prof is None:
+        mats = est.measure_mps_many(mixes, noises)
+    else:
+        with timed(prof, "mps_measure_s", "mps.measure"):
+            mats = est.measure_mps_many(mixes, noises, prof)
+    for w, m in zip(works, mats):
+        w.mat = m
 
 
 def _solve_groups(decisions: List[RepartDecision], prof) -> None:

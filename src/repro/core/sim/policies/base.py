@@ -78,20 +78,27 @@ class EstimateWork:
     """One MPS profiling window collected for a fused estimator pass.
 
     Produced by :meth:`Policy.collect_phase_end`; the owner (BatchSim)
-    groups items by estimator object and fills ``ests`` with one
-    ``estimate_batch`` call per group — one stacked predictor forward for
-    every same-tick window across every replica."""
+    groups items by estimator object, measures the MPS matrix of every
+    window of an estimator that consumes one (one stacked fixed-point pass
+    per group) and fills ``ests`` with one ``estimate_batch`` call per
+    group — one stacked predictor forward for every same-tick window
+    across every replica."""
 
-    __slots__ = ("g", "jids", "profs", "qos", "mat", "ests", "prof")
+    __slots__ = ("g", "jids", "profs", "qos", "mat", "ests", "prof",
+                 "noise")
 
-    def __init__(self, g: GPU, jids, profs, qos, mat, prof=None):
+    def __init__(self, g: GPU, jids, profs, qos, mat, prof=None,
+                 noise=None):
         self.g = g
         self.jids = jids
         self.profs = profs
         self.qos = qos
-        self.mat = mat          # measured MPS matrix (None: estimator-only)
+        self.mat = mat          # measured MPS matrix (None: estimator-only,
+                                # or until the owner measures it in stage A)
         self.ests: Optional[list] = None   # filled by the owner (stage A)
         self.prof = prof        # the replica's profile buckets (None: off)
+        self.noise = noise      # measurement noise drawn at collect time
+                                # for ``mat`` (None: none)
 
 
 class RepartDecision:

@@ -90,18 +90,19 @@ class MisoPolicy(Policy):
 
     def collect_phase_end(self, gs):
         """Collect every MPS window ending at this tick: the mix and its
-        (noise-drawing) measurement happen NOW, in event order — exactly
-        where :meth:`on_phase_end_batch` draws them — so the dedicated
-        noise stream sees the same sequence; only the estimator forward is
-        deferred for cross-replica fusion."""
+        measurement noise are drawn NOW, in event order — exactly where
+        :meth:`on_phase_end_batch` draws them — so the dedicated noise
+        stream sees the same sequence; the noise-free matrix and the
+        estimator forward are deferred to stage A for cross-replica
+        fusion."""
         prof_gs = [g for g in gs if g.phase == MPS_PROF]
         if not prof_gs:
             return None
         work = []
         for g in prof_gs:
             jids, profs, qos = self._mix(g)
-            work.append(EstimateWork(g, jids, profs, qos,
-                                     self._measure(g, profs), self.sim.prof))
+            work.append(EstimateWork(g, jids, profs, qos, None, self.sim.prof,
+                                     self._measure(g, profs, defer=True)))
         return work
 
     def apply_phase_end(self, gs, work):
@@ -204,23 +205,27 @@ class MisoPolicy(Policy):
         qos = [sim.jobs[j].qos_min_slice for j in jids]
         return jids, profs, qos
 
-    def _measure(self, g: GPU, profs):
+    def _measure(self, g: GPU, profs, defer: bool = False):
         """The MPS measurement for ``g``'s window (None for estimators that
-        do not consume one).  Draws measurement noise from the simulator's
-        dedicated stream — call in event order."""
+        do not consume one); with ``defer``, only its noise
+        (``draw_mps_noise``), for stage A to measure the rest.  Draws
+        measurement noise from the simulator's dedicated stream — call in
+        event order."""
         sim = self.sim
-        if not getattr(g.estimator, "needs_mps", False):
+        est = g.estimator
+        if not getattr(est, "needs_mps", False):
             return None
         # thread the simulator's noise stream so every profiling window
         # draws fresh measurement noise (Fig 14 sensitivity) without
         # disturbing the main RNG's failure-injection schedule
+        measure = est.draw_mps_noise if defer else est.measure_mps
         prof = sim.prof
         if prof is None:
-            return g.estimator.measure_mps(
-                profs, noise_sigma=sim.cfg.mps_noise_sigma, rng=sim.noise_rng)
+            return measure(profs, noise_sigma=sim.cfg.mps_noise_sigma,
+                           rng=sim.noise_rng)
         with timed(prof, "mps_measure_s", "mps.measure"):
-            return g.estimator.measure_mps(
-                profs, noise_sigma=sim.cfg.mps_noise_sigma, rng=sim.noise_rng)
+            return measure(profs, noise_sigma=sim.cfg.mps_noise_sigma,
+                           rng=sim.noise_rng)
 
     def _store_estimates(self, g: GPU, jids, ests):
         """Record the estimator's output on the GPU (and the shared

@@ -15,8 +15,9 @@ the host's spans sit on the device trace's clock.
 * ``events`` — events popped off the heap;
 * ``collect_s`` (``sim.collect``) — a replica's ``run_until_collect`` in a
   ``BatchSim`` round;
-* ``mps_measure_s`` (``mps.measure``) — ``MisoPolicy._measure``, the
-  simulated MPS probe and its noise draw;
+* ``mps_measure_s`` (``mps.measure``) — the simulated MPS probe: its
+  noise draw in ``MisoPolicy._measure`` and, in a ``BatchSim`` round, the
+  noise-free matrices that stage A solves for the windows;
 * ``fused_estimator_s`` (``batch.stage_a``) — ``BatchSim`` stage A, whole;
 * ``unet_host_s`` (``unet.host``) — stack the matrices, copy them in, pad,
   launch the forward, crop;
@@ -34,11 +35,16 @@ the host's spans sit on the device trace's clock.
   estimator object, so one per weight set in a fleet of several kinds;
 * ``stage_c_runs`` — stage C runs (each had decisions);
 * ``stage_c_solves`` — stacked ``optimize_partition_batch`` calls inside
-  them, one per (MIG menu, power model, objective).
+  them, one per (MIG menu, power model, objective);
+* ``mps_rows`` — (mix, level) fixed points stage A's MPS probe asked
+  ``PerfModel.mps_matrices`` for, ``mps_rows_hit`` those its memo
+  answered, ``mps_rows_stacked`` those solved in its stacked pass (the
+  rest of the misses were solved one by one).
 
-Nesting: placement, the inline Algorithm 1 and estimator and the MPS probe
-run inside ``collect_s`` or ``round_apply_s``; the U-Net and
-post-processing buckets inside ``fused_estimator_s`` or ``estimator_s``.
+Nesting: placement, the inline Algorithm 1 and estimator and the MPS
+probe's noise draw run inside ``collect_s`` or ``round_apply_s``; the
+U-Net and post-processing buckets inside ``fused_estimator_s`` or
+``estimator_s``, and so does the rest of the MPS probe.
 ``collect_s``, ``fused_estimator_s``, ``fused_alg1_s`` and
 ``round_apply_s`` are disjoint and together make up a replica's rounds.
 Stage A's and stage C's buckets and counters are split across the
@@ -57,7 +63,8 @@ KEYS = ("placement_s", "alg1_s", "estimator_s", "total_s", "events",
         "collect_s", "mps_measure_s", "fused_estimator_s", "unet_host_s",
         "unet_wait_s", "estimator_post_s", "unet_calls", "fused_alg1_s",
         "round_apply_s", "unet_windows", "stage_a_runs", "stage_a_forwards",
-        "stage_c_runs", "stage_c_solves")
+        "stage_c_runs", "stage_c_solves", "mps_rows", "mps_rows_hit",
+        "mps_rows_stacked")
 
 
 def new_prof() -> Dict[str, float]:
